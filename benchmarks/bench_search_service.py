@@ -53,14 +53,13 @@ fusion regressions are diagnosable from the run page.
 With ``--steady-state`` it measures the compile-once serving claim
 directly: per-step latency of a churning mixed cohort served cold vs
 after ``SearchService.precompile`` (asserting zero tracked recompiles
-post-precompile), the fused posterior+EI and fused
-draw+EHVI bucket kernels vs the vmapped XLA chains, and the fused
-launches' static roofline numbers:
+post-precompile), and the fused posterior+EI and fused
+draw+EHVI bucket kernels vs the vmapped XLA chains:
   search_service_steady_cold_step / _warm_step  — us per service step
   search_service_precompile                     — one-time warmup cost
   search_service_steady_misses                  — must be 0
-  fused_posterior_launch / _vs_vmapped_speedup / _roofline_intensity
-  fused_ehvi_launch / _vs_vmapped_speedup / _roofline_intensity
+  fused_posterior_launch / _vs_vmapped_speedup
+  fused_ehvi_launch / _vs_vmapped_speedup
 
 With ``--mesh N`` (or REPRO_BENCH_MESH=N) it forces an N-device host
 platform (``--xla_force_host_platform_device_count``, staged before jax
@@ -435,15 +434,12 @@ def smoke() -> None:
 
 def _fused_kernel_numbers() -> None:
     """The fused posterior+EI bucket kernel vs the vmapped-XLA chain it
-    replaces (one launch vs posterior launch + eager EI), plus static
-    roofline numbers from the fused launch's compiled HLO."""
+    replaces (one launch vs posterior launch + eager EI)."""
     import jax.numpy as jnp
 
     from repro.core.acquisition import expected_improvement
     from repro.core.gp import _batched_posterior
     from repro.kernels.fused_posterior.ops import _fused_launch
-    from repro.launch.hlo_stats import analyze
-    from repro.launch.mesh import MESH_HARDWARE
 
     m, n, q, d = 16, 64, 512, 7
     rng = np.random.default_rng(0)
@@ -477,31 +473,17 @@ def _fused_kernel_numbers() -> None:
     C.emit("fused_posterior_vs_vmapped_speedup", 0.0,
            f"{vmap_s / fused_s:.2f}")
 
-    h = analyze(_fused_launch.lower(*args, impl="xla").compile().as_text())
-    compute_s = h["dot_flops"] / MESH_HARDWARE["peak_flops_bf16"]
-    memory_s = h["dot_bytes"] / MESH_HARDWARE["hbm_bw"]
-    intensity = h["dot_flops"] / max(h["dot_bytes"], 1.0)
-    dominant = "compute" if compute_s >= memory_s else "memory"
-    C.emit("fused_posterior_roofline_intensity", intensity,
-           f"dominant={dominant}")
-
 
 def _fused_ehvi_numbers() -> None:
     """The fused draw+EHVI bucket kernel vs the two-launch chain it
     replaces (eager draw combine -> vmapped box launch, with the raw-
-    scale draw tensor round-tripping through HBM between them), plus
-    static roofline numbers. ``hlo_stats.analyze`` counts dot flops
-    only and the EHVI reduction is dot-free, so the analytic
-    elementwise min/max/clip/product work is added on top — the honest
-    number for a kernel whose arithmetic never touches the MXU."""
+    scale draw tensor round-tripping through HBM between them)."""
     import jax
     import jax.numpy as jnp
 
     from repro.core.acquisition import _ehvi_box_launch
     from repro.core.plan import _draw_launch
     from repro.kernels.fused_ehvi.ops import _fused_ehvi_launch
-    from repro.launch.hlo_stats import analyze
-    from repro.launch.mesh import MESH_HARDWARE
 
     l, d, s, q, k = 8, 2, 64, 512, 64
     rng = np.random.default_rng(0)
@@ -542,23 +524,6 @@ def _fused_ehvi_numbers() -> None:
     C.emit("fused_ehvi_launch", fused_s * 1e6, f"l{l}s{s}q{q}k{k}")
     C.emit("fused_ehvi_vs_vmapped_speedup", 0.0,
            f"{vmap_s / fused_s:.2f}")
-
-    eps = draw(keys).reshape(l, d, s, q)
-    h = analyze(_fused_ehvi_launch.lower(
-        los, his, refs, mu, var, y_mean, y_std, eps,
-        impl="xla").compile().as_text())
-    # ~6 elementwise ops per (dim, box, sample, candidate) cell: min,
-    # max, sub, clip, product-accumulate, sum-accumulate
-    ew_flops = 6.0 * l * d * s * q * k
-    # HBM floor: eps in, boxes in, acquisition out (f32)
-    ew_bytes = 4.0 * (l * d * s * q + 2 * l * k * d + l * q)
-    flops = h["dot_flops"] + ew_flops
-    bytes_ = max(h["dot_bytes"], ew_bytes)
-    compute_s = flops / MESH_HARDWARE["peak_flops_bf16"]
-    memory_s = bytes_ / MESH_HARDWARE["hbm_bw"]
-    dominant = "compute" if compute_s >= memory_s else "memory"
-    C.emit("fused_ehvi_roofline_intensity", flops / bytes_,
-           f"dominant={dominant}")
 
 
 def _fused_fit_numbers() -> None:
@@ -632,8 +597,7 @@ def steady_state() -> None:
     served COLD (every launch shape compiles inline as it first
     appears) vs after ``SearchService.precompile`` has warmed the
     enumerated bucket vocabulary — where ``plan_compile_misses`` must
-    stay exactly 0 — plus the fused posterior kernel comparison and
-    its roofline numbers."""
+    stay exactly 0 — plus the fused kernel comparisons."""
     import dataclasses as dc
 
     from repro.core.plan import CohortLimits
@@ -837,4 +801,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_stats import use_compile_cache
+    use_compile_cache()
     main()

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import sys
 import time
+import traceback
 
 
 def main() -> None:
@@ -16,18 +17,23 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     t0 = time.time()
+    failed = []
     for mod in [bench_roofline, bench_analysis, bench_kernels,
                 bench_search_service,
                 bench_fig7_fig8_fig9, bench_fig3_fig4, bench_fig5_fig6]:
         try:
             mod.main()
-        except Exception as e:  # keep the suite going; record the failure
-            print(f"{mod.__name__},0.0,ERROR:{type(e).__name__}:{e}",
-                  file=sys.stderr)
+        except Exception as e:  # run the rest, then exit non-zero below
+            traceback.print_exc()
             print(f"{mod.__name__.split('.')[-1]}_error,0.0,"
                   f"{type(e).__name__}")
+            failed.append(mod.__name__)
     print(f"total_bench_wall_s,0.0,{time.time() - t0:.1f}")
+    if failed:
+        sys.exit(f"benchmark modules failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_stats import use_compile_cache
+    use_compile_cache()
     main()

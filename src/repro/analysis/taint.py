@@ -31,7 +31,7 @@ from typing import Any, Callable, Dict, List, Sequence, Tuple
 import jax
 import numpy as np
 
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 # Two probes per tainted equation: large positive and a sign-flipped,
 # non-power-of-two magnitude — a value whose effect survives rounding

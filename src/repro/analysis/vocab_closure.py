@@ -65,17 +65,18 @@ def _ehvi_fronts(limits, rng) -> List[Tuple[np.ndarray, np.ndarray]]:
 def iter_live_plans(planner, limits) -> Iterable:
     """Exhaustively yield planned cohort steps over the reachable
     exact-shape states: every observation count and lane count for
-    posterior/sample/loo buckets, every remaining-candidate count and
+    posterior/sample/loo buckets, every remaining-candidate count a
+    session holding at most ``max_obs`` observations can score and every
     front in ``_ehvi_fronts`` for EHVI buckets (lane counts thinned to
-    {1, 2, max} there — the lane axis rounds identically across
-    kinds)."""
+    {1, 2, max} there, capped at the kind's lane bound — the lane axis
+    rounds identically across kinds)."""
     from repro.core.plan import (EhviQuery, FitQuery, LooSampleQuery,
                                  PosteriorQuery, SampleQuery)
     rng = np.random.default_rng(7)
     d, qg = limits.d, limits.q_grid
     grid = np.zeros((qg, d), np.float32)
     lane_counts = range(1, limits.max_lanes + 1)
-    thin_lanes = sorted({1, 2, limits.max_lanes})
+    thin_lanes = sorted({1, min(2, limits.max_lanes), limits.max_lanes})
     for n in range(1, limits.max_obs + 1):
         for lanes in lane_counts:
             yield planner.plan(
@@ -110,14 +111,16 @@ def iter_live_plans(planner, limits) -> Iterable:
                                   np.zeros((n,), np.float32),
                                   noise, steps)] * lanes)
     fronts = _ehvi_fronts(limits, rng)
+    moo = limits.max_moo_sessions or limits.max_lanes
+    moo_lanes = sorted({1, min(2, moo), moo})
     for n_obj in limits.n_objectives:
         for s in limits.n_mc:
-            for q in range(1, qg + 1):
+            for q in range(max(1, qg - limits.max_obs), qg + 1):
                 row = np.zeros((q,), np.float32)
                 for observed, ref in fronts:
                     if observed.shape[-1] != n_obj:
                         continue
-                    for lanes in thin_lanes:
+                    for lanes in moo_lanes:
                         yield planner.plan([EhviQuery(
                             samples=None, observed=observed, ref=ref,
                             mu=(row,) * n_obj, var=(row,) * n_obj,

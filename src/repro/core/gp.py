@@ -46,6 +46,9 @@ from repro.kernels.matern import matern52
 from repro.kernels.routing import resolve_impl
 
 JITTER = 1e-6
+# GP dots run at full f32: the TPU's default f32 matmul rounds its
+# inputs to bf16, too coarse for the posterior's solves and means
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +86,7 @@ def _nlml(params: GPParams, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     k = _kernel(params, x, x) + (params.noise + JITTER) * jnp.eye(n)
     chol = jnp.linalg.cholesky(k)
     alpha = jax.scipy.linalg.cho_solve((chol, True), y)
-    return (0.5 * y @ alpha
+    return (0.5 * jnp.dot(y, alpha, precision=HIGHEST)
             + jnp.sum(jnp.log(jnp.diagonal(chol)))
             + 0.5 * n * jnp.log(2.0 * jnp.pi))
 
@@ -148,7 +151,7 @@ def gp_posterior(gp: GP, xq: jnp.ndarray,
     """Posterior mean/variance on the standardised scale. xq: (m, d)."""
     xq = jnp.asarray(xq, jnp.float32)
     ks = _kernel(gp.params, xq, gp.x, impl=impl)        # (m, n)
-    mu = ks @ gp.alpha
+    mu = jnp.dot(ks, gp.alpha, precision=HIGHEST)
     v = jax.scipy.linalg.solve_triangular(gp.chol, ks.T, lower=True)
     kss = jnp.exp(gp.params.log_signal)                  # diag of k(x,x)
     var = jnp.maximum(kss - jnp.sum(v * v, axis=0), 1e-10)
@@ -235,7 +238,7 @@ def _masked_nlml(params: GPParams, x: jnp.ndarray, y: jnp.ndarray,
     chol = jnp.linalg.cholesky(k)
     alpha = jax.scipy.linalg.cho_solve((chol, True), y)
     n = jnp.sum(mask)
-    return (0.5 * y @ alpha
+    return (0.5 * jnp.dot(y, alpha, precision=HIGHEST)
             + jnp.sum(jnp.log(jnp.diagonal(chol)) * mask)
             + 0.5 * n * jnp.log(2.0 * jnp.pi))
 
@@ -470,7 +473,7 @@ def _batched_posterior(log_ls, log_sf, x, mask, chol, alpha, xq,
     def one(ls, sf, xi, mi, ci, ai, xqi):
         params = GPParams(ls, sf, 0.0)
         ks = _kernel(params, xqi, xi, impl=impl) * mi[None, :]  # (q, n_max)
-        mu = ks @ ai
+        mu = jnp.dot(ks, ai, precision=HIGHEST)
         v = jax.scipy.linalg.solve_triangular(ci, ks.T, lower=True)
         var = jnp.maximum(jnp.exp(sf) - jnp.sum(v * v, axis=0), 1e-10)
         return mu, var

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.distributed import mesh_axis_size, shard_map
+from repro.distributed import auto_axes, mesh_axis_size, shard_map
 from repro.kernels.routing import resolve_impl
 
 from .acquisition import (EHVI_BOX_CHUNK, _ehvi_box_launch,
@@ -230,7 +230,11 @@ class CohortLimits:
     fixed noise levels the (jit-static) fit launches will see.
     ``max_ehvi_boxes`` bounds the box-decomposition size of any front
     (2-objective fronts decompose into at most ``front+1`` staircase
-    boxes; n>=3 fronts grow faster and dominate the vocabulary)."""
+    boxes; n>=3 fronts grow faster and dominate the vocabulary).
+    ``max_moo_sessions`` bounds the EHVI launch's lane axis, one lane
+    per multi-objective session of a step (``None``: ``max_lanes``) —
+    in a mixed cohort it is far below the model-lane bound, and the
+    EHVI kind is the largest share of the vocabulary."""
     d: int
     q_grid: int
     max_obs: int
@@ -242,6 +246,7 @@ class CohortLimits:
     noises: Tuple[float, ...] = (0.1,)
     fit_steps: int = 120
     fit_warm_steps: int = 16
+    max_moo_sessions: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -289,7 +294,7 @@ class StepPlanner:
         # data-axis size, so shard_map splits each launch evenly across
         # devices. ``lane_shards`` overrides the divisor directly (for
         # policy tests on single-device hosts).
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh)
         self.data_axis = data_axis
         self.lane_shards = (mesh_axis_size(mesh, data_axis)
                             if lane_shards is None else int(lane_shards))
@@ -443,6 +448,16 @@ class StepPlanner:
         step = max(1, self.q_round_to)
         return list(range(step, self.round_grid(max_q) + 1, step))
 
+    def ehvi_grid_pads(self, limits: CohortLimits) -> List[int]:
+        """The EHVI candidate-axis vocabulary: a session scores its
+        remaining candidates, ``q_grid`` minus the configurations it has
+        profiled, and it never holds more than ``max_obs`` observations —
+        so only the grid buckets of ``[q_grid - max_obs, q_grid]``
+        occur."""
+        lo = max(1, limits.q_grid - limits.max_obs)
+        return sorted({self.round_grid(q)
+                       for q in range(lo, limits.q_grid + 1)})
+
     def _lane_pads(self, max_lanes: int) -> List[int]:
         # every fixed point of round_models up to the bound: the pow2
         # ladder, each rung lifted to a shard multiple when a mesh is
@@ -488,7 +503,8 @@ class StepPlanner:
         buckets) and the sample count; LOO launches vary (n_pad, l_pad)
         per sample count; EHVI launches vary the candidate bucket (the
         remaining-candidate set shrinks every iteration), the box-axis
-        pad, and the MOO lane pad per (n_obj, n_mc); fit launches vary
+        pad, and the MOO lane pad (up to ``max_moo_sessions``) per
+        (n_obj, n_mc); fit launches vary
         (n_pad, m_pad) per (noise, steps-rung) — the warm and cold
         schedule lengths from ``fit_step_rungs``."""
         out: List[Bucket] = []
@@ -512,11 +528,13 @@ class StepPlanner:
                     out.append(Bucket("loo", (s, n_pad), (),
                                       {"n_pad": n_pad, "l_pad": l_pad,
                                        "lanes": l_pad}))
+        moo_lanes = self._lane_pads(limits.max_moo_sessions
+                                    or limits.max_lanes)
         for n_obj in limits.n_objectives:
             for s in limits.n_mc:
-                for q_pad in self._grid_pads(limits.q_grid):
+                for q_pad in self.ehvi_grid_pads(limits):
                     for k_pad in self._box_pads(limits.max_ehvi_boxes):
-                        for l_pad in lanes:
+                        for l_pad in moo_lanes:
                             out.append(Bucket(
                                 "ehvi", (n_obj, s, q_pad), (),
                                 {"k_pad": k_pad, "q_pad": q_pad,
@@ -801,7 +819,7 @@ class PlanExecutor:
         self.fused_ehvi = fused_ehvi
         self.donate = (jax.default_backend() == "tpu" if donate is None
                        else bool(donate))
-        self.mesh = mesh
+        self.mesh = auto_axes(mesh)
         self.data_axis = data_axis
         self.lane_shards = mesh_axis_size(mesh, data_axis)
 
@@ -813,6 +831,26 @@ class PlanExecutor:
             return sharded_bucket_launch(self.mesh, self.data_axis, kind,
                                          self.donate)
         return donated if self.donate else plain
+
+    def bucket_impl(self, bucket: Bucket, impl: Optional[str] = None
+                    ) -> str:
+        """The kernel impl one bucket's launch runs: ``impl`` (default:
+        the executor's) with ``"auto"`` resolved on the launch's cell
+        count per shard. Kinds without a Pallas twin on this executor
+        (LOO, draws, the vmapped EHVI chain) run XLA."""
+        impl = self.impl if impl is None else impl
+        kind, key, p = bucket.kind, bucket.key, bucket.pads
+        if kind == "posterior":
+            cells = p["m_pad"] * key[0] * p["n_pad"]
+        elif kind == "sample":
+            cells = p["m_pad"] * p["q_pad"] * p["n_pad"]
+        elif kind == "fit":
+            cells = p["m_pad"] * p["n_pad"] * p["n_pad"] * key[1]
+        elif kind == "ehvi" and self.fused_ehvi:
+            cells = p["l_pad"] * key[1] * p["q_pad"] * p["k_pad"]
+        else:
+            return "xla"
+        return resolve_impl(impl, cells=cells, shards=self.lane_shards)
 
     def execute(self, plan: StepPlan, *, counters: Optional[dict] = None,
                 impl: Optional[str] = None) -> List[Any]:
@@ -892,8 +930,7 @@ class PlanExecutor:
         n_pad, m_pad = bucket.pads["n_pad"], bucket.pads["m_pad"]
         parts = self._fresh_parts(
             queries, self._stack_parts(queries, n_pad, q, d))
-        r_impl = resolve_impl(impl, cells=m_pad * q * n_pad,
-                              shards=self.lane_shards)
+        r_impl = self.bucket_impl(bucket, impl)
         if self.fused_posterior:
             from repro.kernels.fused_posterior import fused_launch_fn
             # per-lane incumbents; lanes without an EI head get 0.0 (the
@@ -943,8 +980,7 @@ class PlanExecutor:
         if q_pad > q:
             eps = jnp.pad(eps, ((0, 0), (0, 0), (0, q_pad - q)))
         parts = self._pad_lanes(parts + [eps], m_pad)
-        r_impl = resolve_impl(impl, cells=m_pad * q_pad * n_pad,
-                              shards=self.lane_shards)
+        r_impl = self.bucket_impl(bucket, impl)
         launch = self._launch("sample", _batched_sample_launch,
                               _batched_sample_launch_donated)
         s = launch(*parts, impl=r_impl)
@@ -1080,8 +1116,7 @@ class PlanExecutor:
                  for a in (los, his, refs, mus, vars_, yms, yss)]
         parts.append(jnp.stack(epss))
         parts = self._pad_lanes(parts, l_pad)
-        r_impl = resolve_impl(impl, cells=l_pad * s * q_pad * k_pad,
-                              shards=self.lane_shards)
+        r_impl = self.bucket_impl(bucket, impl)
         # every argument is rebuilt per step (host-assembled stacks,
         # fresh draws), so the donating twin is alias-safe here too
         launch = self._launch("fused_ehvi",
@@ -1127,8 +1162,7 @@ class PlanExecutor:
         # returned BatchedGP
         gils = jnp.asarray(ils)
         gisf = jnp.asarray(isf)
-        r_impl = resolve_impl(impl, cells=m_pad * n_pad * n_pad * steps,
-                              shards=self.lane_shards)
+        r_impl = self.bucket_impl(bucket, impl)
         launch = self._launch("fused_fit",
                               fused_fit_launch_fn(donate=False),
                               fused_fit_launch_fn(donate=True))

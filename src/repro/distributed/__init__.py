@@ -1,5 +1,5 @@
-from .context import (DistContext, get_context, mesh_axis_size, set_context,
-                      shard_map, use_context)
+from .context import (DistContext, auto_axes, get_context, mesh_axis_size,
+                      set_context, shard_map, use_context)
 
-__all__ = ["DistContext", "get_context", "mesh_axis_size", "set_context",
-           "shard_map", "use_context"]
+__all__ = ["DistContext", "auto_axes", "get_context", "mesh_axis_size",
+           "set_context", "shard_map", "use_context"]

@@ -12,22 +12,30 @@ import dataclasses
 from typing import Optional, Tuple
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across jax versions: newer releases expose it at
-    the top level (keyword ``check_vma``); older ones only under
-    ``jax.experimental.shard_map`` where the same flag is ``check_rep``.
-    Every shard-mapped launch in this package routes through here so the
-    plan executor and the model layers agree on one resolution."""
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        return top(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
+    """``jax.shard_map`` with keyword arguments. Every shard-mapped launch
+    in this package routes through here so the plan executor and the
+    model layers agree on one call form."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
+
+
+def auto_axes(mesh: Optional[Mesh]) -> Optional[Mesh]:
+    """The same devices and axis names with every axis ``Auto``.
+
+    ``jax.make_mesh`` builds ``Explicit`` axes, on which an array's
+    sharding is part of its type: indexing a lane-sharded launch output
+    with a host integer then raises ``ShardingTypeError``. The plan
+    layer shards only inside its own ``shard_map`` launches and slices
+    their outputs per query on the host side, so it holds its mesh with
+    ``Auto`` axes and leaves the partitioning of those slices to XLA."""
+    if mesh is None:
+        return None
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def mesh_axis_size(mesh: Optional[Mesh], axis: str) -> int:

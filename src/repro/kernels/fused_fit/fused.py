@@ -40,24 +40,39 @@ JITTER = 1e-6
 R2_SHIFT = 1e-12
 
 
-def _kernel_parts(ls, sf, x, mask1, noise, row_ids, col_ids):
-    """K, K_data, dK/dr2 and scaled inputs at params (ls, sf) — the
-    in-core twin of ``ref._masked_kernel_parts``."""
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _col_to_row(col, eye):
+    """(n, 1) -> (1, n) by a diagonal select and a sublane sum: exact
+    (it adds zeros) and free of any relayout of an unaligned vector."""
+    return jnp.sum(jnp.where(eye, col, 0.0), axis=0, keepdims=True)
+
+
+def _row_to_col(row, eye):
+    """(1, n) -> (n, 1), the twin of ``_col_to_row``."""
+    return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+
+def _kernel_parts(ls, sf, x, mask_row, mask_col, noise, row_ids, col_ids,
+                  eye):
+    """K, K_data, dK/dr2 and scaled inputs at params ``ls`` (1, d) and
+    ``sf`` (1, 1) — the in-core twin of ``ref._masked_kernel_parts``.
+    A per-observation vector lives as an (n, 1) column or a (1, n) row,
+    never 1-D."""
     xt = x * jnp.exp(-ls)                                  # (n, d)
-    sq = jnp.sum(xt * xt, axis=1)                          # (n,)
+    sq_col = jnp.sum(xt * xt, axis=1, keepdims=True)       # (n, 1)
     dots = jax.lax.dot_general(
-        xt, xt, (((1,), (1,)), ((), ())),
+        xt, xt, (((1,), (1,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)                # (n, n)
-    d2 = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * dots, 0.0)
+    d2 = jnp.maximum(sq_col + _col_to_row(sq_col, eye) - 2.0 * dots, 0.0)
     r = jnp.sqrt(d2 + R2_SHIFT)
     e = jnp.exp(-SQRT5 * r)
     mval = (1.0 + SQRT5 * r + 5.0 / 3.0 * d2) * e
-    mo = mask1[:, None] * mask1[None, :]
+    mo = mask_col * mask_row
     sfe = jnp.exp(sf)
     kd = sfe * mval * mo
-    diag = jnp.where(row_ids == col_ids,
-                     (noise + JITTER + 1.0 - mask1)[:, None], 0.0)
-    k = kd + diag
+    k = kd + jnp.where(eye, noise + JITTER + 1.0 - mask_col, 0.0)
     # Diagonal excluded explicitly: see ref._masked_kernel_parts.
     p = jnp.where((d2 > 0.0) & (row_ids != col_ids),
                   -(5.0 / 6.0) * sfe * (1.0 + SQRT5 * d2 / r) * e * mo,
@@ -65,35 +80,36 @@ def _kernel_parts(ls, sf, x, mask1, noise, row_ids, col_ids):
     return k, kd, p, xt
 
 
-def _chol_inv(k, n, row_ids, col_ids):
+def _chol_inv(k, n, row_ids, col_ids, eye):
     """Cholesky factor L of ``k`` and V = L^{-1}, by column-wise Crout
-    then forward substitution against the identity."""
-    iota_col = row_ids[:, :1]                              # (n, 1) row index
+    then forward substitution against the identity. Rows and columns
+    are picked by one-hot masked sums and the matrix-vector products
+    are multiply-reduces on the VPU, so every value stays 2-D."""
+    iota_col = row_ids[:, :1]                              # (n, 1)
+    iota_row = col_ids[:1, :]                              # (1, n)
 
     def chol_col(j, l):
-        oh = (iota_col == j).astype(jnp.float32)           # (n, 1) one-hot j
-        krow = jnp.sum(k * oh, axis=0)                     # row j == col j (sym)
-        lrow = jnp.sum(l * oh, axis=0)                     # (n,) row j of L
-        s = jax.lax.dot_general(
-            l, lrow[:, None], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]      # L @ lrow
-        c = krow - s
-        cj = jnp.sum(c * oh[:, 0])
-        col = c / jnp.sqrt(cj)
-        col = jnp.where(iota_col[:, 0] >= j, col, 0.0)
-        return jnp.where(col_ids == j, col[:, None], l)
+        kcol = jnp.sum(jnp.where(iota_row == j, k, 0.0), axis=1,
+                       keepdims=True)                      # K[:, j]
+        lrow = jnp.sum(jnp.where(iota_col == j, l, 0.0), axis=0,
+                       keepdims=True)                      # L[j, :]
+        c = kcol - jnp.sum(l * lrow, axis=1, keepdims=True)
+        cj = jnp.sum(jnp.where(iota_col == j, c, 0.0), axis=0,
+                     keepdims=True)                        # (1, 1)
+        col = jnp.where(iota_col >= j, c / jnp.sqrt(cj), 0.0)
+        return jnp.where(col_ids == j, col, l)
 
     l = jax.lax.fori_loop(0, n, chol_col, jnp.zeros_like(k))
 
     def sub_row(j, v):
-        oh = (iota_col == j).astype(jnp.float32)
-        lrow = jnp.sum(l * oh, axis=0)                     # (n,)
-        ljj = jnp.sum(lrow * oh[:, 0])
-        acc = jax.lax.dot_general(
-            lrow[None, :], v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[0]         # lrow @ V
-        vrow = (oh[:, 0] - acc) / ljj
-        return jnp.where(row_ids == j, vrow[None, :], v)
+        lrow = jnp.sum(jnp.where(iota_col == j, l, 0.0), axis=0,
+                       keepdims=True)                      # L[j, :]
+        ljj = jnp.sum(jnp.where(iota_row == j, lrow, 0.0), axis=1,
+                      keepdims=True)                       # (1, 1)
+        acc = jnp.sum(_row_to_col(lrow, eye) * v, axis=0,
+                      keepdims=True)                       # L[j, :] @ V
+        vrow = (jnp.where(iota_row == j, 1.0, 0.0) - acc) / ljj
+        return jnp.where(row_ids == j, vrow, v)
 
     v = jax.lax.fori_loop(0, n, sub_row, jnp.zeros_like(k))
     return l, v
@@ -103,61 +119,63 @@ def _fused_fit_kernel(x_ref, y_ref, mask_ref, ils_ref, isf_ref,
                       ls_out, sf_out, chol_out, alpha_out,
                       *, steps: int, noise: float, lr: float, n: int):
     x = x_ref[0]                                           # (n, d)
-    y = y_ref[0]                                           # (n,)
-    mask1 = mask_ref[0]                                    # (n,)
+    y_row = y_ref[0]                                       # (1, n)
+    mask_row = mask_ref[0]                                 # (1, n)
     row_ids = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
     col_ids = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    eye = row_ids == col_ids
+    mask_col = _row_to_col(mask_row, eye)
 
     def alpha_of(v):
-        w = jax.lax.dot_general(
-            v, y[:, None], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]      # V y
-        return jax.lax.dot_general(
-            w[None, :], v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[0]         # V^T (V y)
+        w = jnp.sum(v * y_row, axis=1, keepdims=True)      # V y, (n, 1)
+        return jnp.sum(v * w, axis=0, keepdims=True)       # (V^T V y)^T
 
     def adam_step(i, carry):
         ls, sf, m_ls, m_sf, v_ls, v_sf = carry
-        k, kd, p, xt = _kernel_parts(ls, sf, x, mask1, noise,
-                                     row_ids, col_ids)
-        _, v = _chol_inv(k, n, row_ids, col_ids)
-        alpha = alpha_of(v)
+        k, kd, p, xt = _kernel_parts(ls, sf, x, mask_row, mask_col, noise,
+                                     row_ids, col_ids, eye)
+        _, v = _chol_inv(k, n, row_ids, col_ids, eye)
+        alpha = alpha_of(v)                                # (1, n)
         kinv = jax.lax.dot_general(
-            v, v, (((0,), (0,)), ((), ())),
+            v, v, (((0,), (0,)), ((), ())), precision=HIGHEST,
             preferred_element_type=jnp.float32)            # V^T V
-        g = kinv - alpha[:, None] * alpha[None, :]
-        g_sf = 0.5 * jnp.sum(g * kd)
+        g = kinv - _row_to_col(alpha, eye) * alpha
+        g_sf = 0.5 * jnp.sum(jnp.sum(g * kd, axis=0, keepdims=True),
+                             axis=1, keepdims=True)        # (1, 1)
         a = g * p
-        ra = jnp.sum(a, axis=1)
-        term1 = jnp.sum(xt * xt * ra[:, None], axis=0)
+        ra = jnp.sum(a, axis=1, keepdims=True)             # (n, 1)
+        term1 = jnp.sum(xt * xt * ra, axis=0, keepdims=True)
         b = jax.lax.dot_general(
-            a, xt, (((1,), (0,)), ((), ())),
+            a, xt, (((1,), (0,)), ((), ())), precision=HIGHEST,
             preferred_element_type=jnp.float32)            # A @ Xt
-        term2 = jnp.sum(xt * b, axis=0)
-        g_ls = 2.0 * term2 - 2.0 * term1
+        term2 = jnp.sum(xt * b, axis=0, keepdims=True)
+        g_ls = 2.0 * term2 - 2.0 * term1                   # (1, d)
         m_ls = 0.9 * m_ls + 0.1 * g_ls
         m_sf = 0.9 * m_sf + 0.1 * g_sf
         v_ls = 0.999 * v_ls + 0.001 * g_ls * g_ls
         v_sf = 0.999 * v_sf + 0.001 * g_sf * g_sf
-        t = jnp.float32(i) + 1.0
-        c1 = 1.0 - 0.9 ** t
-        c2 = 1.0 - 0.999 ** t
+        # bias corrections 1 - beta^t as exp(t log beta), on a (1, 1)
+        # vector: the scalar unit has no transcendental ops
+        t = jnp.full((1, 1), i + 1, jnp.int32).astype(jnp.float32)
+        c1 = 1.0 - jnp.exp(t * np.log(np.float32(0.9)))
+        c2 = 1.0 - jnp.exp(t * np.log(np.float32(0.999)))
         ls = ls - lr * (m_ls / c1) / (jnp.sqrt(v_ls / c2) + 1e-8)
         sf = sf - lr * (m_sf / c1) / (jnp.sqrt(v_sf / c2) + 1e-8)
         ls = jnp.clip(ls, -3.0, 3.0)
         sf = jnp.clip(sf, -3.0, 3.0)
         return ls, sf, m_ls, m_sf, v_ls, v_sf
 
-    d = x.shape[-1]
-    init = (ils_ref[0], isf_ref[0, 0],
-            jnp.zeros((d,), jnp.float32), jnp.float32(0.0),
-            jnp.zeros((d,), jnp.float32), jnp.float32(0.0))
+    ls0 = ils_ref[0]                                       # (1, d)
+    sf0 = isf_ref[0]                                       # (1, 1)
+    init = (ls0, sf0, jnp.zeros_like(ls0), jnp.zeros_like(sf0),
+            jnp.zeros_like(ls0), jnp.zeros_like(sf0))
     ls, sf, _, _, _, _ = jax.lax.fori_loop(0, steps, adam_step, init)
 
-    k, _, _, _ = _kernel_parts(ls, sf, x, mask1, noise, row_ids, col_ids)
-    l, v = _chol_inv(k, n, row_ids, col_ids)
+    k, _, _, _ = _kernel_parts(ls, sf, x, mask_row, mask_col, noise,
+                               row_ids, col_ids, eye)
+    l, v = _chol_inv(k, n, row_ids, col_ids, eye)
     ls_out[0] = ls
-    sf_out[0, 0] = sf
+    sf_out[0] = sf
     chol_out[0] = l
     alpha_out[0] = alpha_of(v)
 
@@ -166,7 +184,10 @@ def fused_fit_pallas(x, y, mask, init_ls, init_sf, *,
                      steps: int = 120, noise: float = 0.1,
                      lr: float = 0.05, interpret: bool = False):
     """x: (m, n, d), y/mask: (m, n), init_ls: (m, d), init_sf: (m,)
-    -> (log_ls, log_sf, chol, alpha) — one Pallas launch per bucket."""
+    -> (log_ls, log_sf, chol, alpha) — one Pallas launch per bucket.
+
+    Per-lane vectors travel as (m, 1, x) arrays and scalars as
+    (m, 1, 1), so every block's last two dims equal the array's."""
     m, n, d = x.shape
     pn = 0 if interpret else (-n) % 128
     pd = 0 if interpret else (-d) % 128
@@ -178,30 +199,26 @@ def fused_fit_pallas(x, y, mask, init_ls, init_sf, *,
     np_, dp = n + pn, d + pd
     kern = functools.partial(_fused_fit_kernel, steps=steps, noise=noise,
                              lr=lr, n=np_)
+
+    def lane(*shape):
+        return pl.BlockSpec((1,) + shape, lambda i: (i, 0, 0))
+
     ls, sf, chol, alpha = pl.pallas_call(
         kern,
         grid=(m,),
-        in_specs=[
-            pl.BlockSpec((1, np_, dp), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, dp), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-            pl.BlockSpec((1, np_, np_), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, np_), lambda i: (i, 0)),
-        ],
+        in_specs=[lane(np_, dp), lane(1, np_), lane(1, np_), lane(1, dp),
+                  lane(1, 1)],
+        out_specs=[lane(1, dp), lane(1, 1), lane(np_, np_), lane(1, np_)],
         out_shape=[
-            jax.ShapeDtypeStruct((m, dp), jnp.float32),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1, dp), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1, 1), jnp.float32),
             jax.ShapeDtypeStruct((m, np_, np_), jnp.float32),
-            jax.ShapeDtypeStruct((m, np_), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1, np_), jnp.float32),
         ],
         interpret=interpret,
-    )(x.astype(jnp.float32), y.astype(jnp.float32),
-      mask.astype(jnp.float32), init_ls.astype(jnp.float32),
-      jnp.asarray(init_sf, jnp.float32).reshape(m, 1))
-    return (ls[:, :d], sf[:, 0], chol[:, :n, :n], alpha[:, :n])
+    )(x.astype(jnp.float32),
+      y.astype(jnp.float32).reshape(m, 1, np_),
+      mask.astype(jnp.float32).reshape(m, 1, np_),
+      init_ls.astype(jnp.float32).reshape(m, 1, dp),
+      jnp.asarray(init_sf, jnp.float32).reshape(m, 1, 1))
+    return (ls[:, 0, :d], sf[:, 0, 0], chol[:, :n, :n], alpha[:, 0, :n])

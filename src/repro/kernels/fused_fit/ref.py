@@ -37,6 +37,7 @@ import jax.numpy as jnp
 SQRT5 = 5.0 ** 0.5
 JITTER = 1e-6            # matches core.gp.JITTER
 R2_SHIFT = 1e-12         # matches kernels.matern sqrt shift
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _masked_kernel_parts(log_ls, log_sf, x, mask, noise):
@@ -49,7 +50,7 @@ def _masked_kernel_parts(log_ls, log_sf, x, mask, noise):
     xt = x / ls
     sq = jnp.sum(xt * xt, axis=1)
     d2 = jnp.maximum(sq[:, None] + sq[None, :]
-                     - 2.0 * (xt @ xt.T), 0.0)
+                     - 2.0 * jnp.dot(xt, xt.T, precision=HIGHEST), 0.0)
     r = jnp.sqrt(d2 + R2_SHIFT)
     e = jnp.exp(-SQRT5 * r)
     mval = (1.0 + SQRT5 * r + 5.0 / 3.0 * d2) * e
@@ -82,7 +83,8 @@ def _masked_nlml_grads(log_ls, log_sf, x, y, mask, noise):
     a = g * p
     ra = jnp.sum(a, axis=1)
     term1 = jnp.sum(xt * xt * ra[:, None], axis=0)       # (Xt^2)^T rA
-    term2 = jnp.sum(xt * (a @ xt), axis=0)               # diag(Xt^T A Xt)
+    term2 = jnp.sum(xt * jnp.dot(a, xt, precision=HIGHEST),
+                    axis=0)                              # diag(Xt^T A Xt)
     g_ls = 2.0 * term2 - 2.0 * term1
     return g_ls, g_sf
 

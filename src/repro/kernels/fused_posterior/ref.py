@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 SQRT5 = 5.0 ** 0.5
+HIGHEST = jax.lax.Precision.HIGHEST
 VAR_FLOOR = 1e-12        # must match core.acquisition.VAR_FLOOR
 INV_SQRT2 = 2.0 ** -0.5
 INV_SQRT_2PI = float(1.0 / jnp.sqrt(2.0 * jnp.pi))
@@ -21,7 +22,7 @@ INV_SQRT_2PI = float(1.0 / jnp.sqrt(2.0 * jnp.pi))
 
 def _matern52(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     d2 = (jnp.sum(a * a, 1)[:, None] + jnp.sum(b * b, 1)[None, :]
-          - 2.0 * (a @ b.T))
+          - 2.0 * jnp.dot(a, b.T, precision=HIGHEST))
     r = jnp.sqrt(jnp.maximum(d2, 0.0) + 1e-12)
     return (1.0 + SQRT5 * r + 5.0 / 3.0 * d2) * jnp.exp(-SQRT5 * r)
 
@@ -44,7 +45,7 @@ def fused_posterior_ei_ref(log_ls, log_sf, x, mask, chol, alpha, xq, best):
         scale = jnp.exp(ls)
         ks = (jnp.exp(sf) * _matern52(xqi / scale, xi / scale)
               * mi[None, :])                                   # (q, n)
-        mu = ks @ ai
+        mu = jnp.dot(ks, ai, precision=HIGHEST)
         v = jax.scipy.linalg.solve_triangular(ci, ks.T, lower=True)
         var = jnp.maximum(jnp.exp(sf) - jnp.sum(v * v, axis=0), 1e-10)
         return mu, var, _ei(mu, var, bi)

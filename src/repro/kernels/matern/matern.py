@@ -23,6 +23,7 @@ def _matern_kernel(a_ref, b_ref, o_ref):
     a = a_ref[...].astype(jnp.float32)      # (bm, d)
     b = b_ref[...].astype(jnp.float32)      # (bn, d)
     ab = jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     d2 = (jnp.sum(a * a, 1)[:, None] + jnp.sum(b * b, 1)[None, :]
           - 2.0 * ab)

@@ -26,6 +26,7 @@ def _pearson_kernel(a_ref, b_ref, o_ref, *, d_valid: int):
     ac = (a - am) * mask
     bc = (b - bm) * mask
     num = jax.lax.dot_general(ac, bc, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     an = jnp.sqrt(jnp.sum(ac * ac, axis=1))
     bn = jnp.sqrt(jnp.sum(bc * bc, axis=1))

@@ -7,6 +7,7 @@ distance operator", paper §IV-E), which is why it gets a kernel.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -15,7 +16,7 @@ def pairwise_pearson_ref(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     b = b.astype(jnp.float32)
     ac = a - jnp.mean(a, axis=1, keepdims=True)
     bc = b - jnp.mean(b, axis=1, keepdims=True)
-    num = ac @ bc.T
+    num = jnp.dot(ac, bc.T, precision=jax.lax.Precision.HIGHEST)
     den = (jnp.sqrt(jnp.sum(ac * ac, axis=1))[:, None]
            * jnp.sqrt(jnp.sum(bc * bc, axis=1))[None, :])
     return num / jnp.maximum(den, 1e-12)

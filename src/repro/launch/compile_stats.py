@@ -22,7 +22,30 @@ many compiles warming the vocabulary actually cost.
 """
 from __future__ import annotations
 
+import os
 from typing import Dict
+
+import jax
+
+# <checkout>/src/repro/launch/compile_stats.py -> <checkout>
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    the directory and nothing here overrides it; otherwise the cache is
+    ``<checkout>/.jax_cache`` — one fixed path, since the path is part
+    of what a later run must find again. The precompiled bucket
+    vocabulary is hundreds of sub-second programs, so the minimum
+    compile time for an entry is lifted to zero."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 # shard-mapped launch twins register here as they are constructed: the
 # static vocabulary below is closed, but the sharded twins are minted
@@ -97,7 +120,11 @@ def tracked_launches() -> Dict[str, object]:
 
 def _cache_size(fn) -> int:
     size = getattr(fn, "_cache_size", None)
-    return int(size()) if callable(size) else 0
+    if not callable(size):
+        raise TypeError(
+            f"tracked launch {fn!r} has no jit cache to count: its "
+            f"compiles would read as zero plan_compile_misses")
+    return int(size())
 
 
 def cache_sizes() -> Dict[str, int]:

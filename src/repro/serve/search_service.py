@@ -74,8 +74,7 @@ from repro.core.bo import (KEY_PURPOSE_MOO_EHVI, KEY_PURPOSE_RGPE, BOConfig,
                            _model_posteriors_augmented, _should_stop_early,
                            _target_runs, derive_key)
 from repro.core.encoding import SearchSpace
-from repro.core.gp import (GP, BatchedGP, GPParams, _pad_stack_obs,
-                           batched_posterior)
+from repro.core.gp import GP, BatchedGP, GPParams, batched_posterior
 from repro.core.repository import Repository
 from repro.core.rgpe import WeightJob, mix_weighted
 from repro.kernels.ranking_loss import ranking_loss_launch_fn
@@ -440,13 +439,11 @@ class SearchService:
         (``enumerate_buckets``); each bucket is driven through the REAL
         executor path with a dummy query pinned at the bucket's padded
         shape — executing (not just AOT-lowering) is deliberate: in
-        current jax ``lower().compile()`` does not populate the jit call
-        cache, and only the executed path exercises the identical impl
-        routing and kernel dispatch serving will use. The target fit
-        leg is part of the enumerated vocabulary (fit buckets walk both
-        the warm and cold ``steps`` rungs); the legacy vmapped fit
-        launches are ALSO warmed from the same limits — the support-
-        model store still fits through ``fit_targets``. The padded
+        jax 0.9 ``lower().compile()`` still does not populate the jit
+        call cache, and only the executed path exercises the identical
+        impl routing and kernel dispatch serving will use. The target
+        fit leg is part of the enumerated vocabulary (fit buckets walk
+        both the warm and cold ``steps`` rungs). The padded
         ranking-loss launch (the RGPE scoring hot spot) is warmed over
         its limits-closed shape set too: its row count is the step's
         ensemble rows — at most ``max_lanes`` stacks of ``n_samples``
@@ -462,13 +459,6 @@ class SearchService:
                 [dataclasses.replace(
                     bucket, indices=tuple(range(len(queries))))],
                 prep))
-        for noise in limits.noises:
-            for n_pad in self.planner._obs_pads(limits.max_obs):
-                for m_pad in self.planner._lane_pads(limits.max_lanes):
-                    self.planner.fit_targets(
-                        [np.zeros((n_pad, limits.d), np.float32)] * m_pad,
-                        [np.arange(n_pad, dtype=np.float32)] * m_pad,
-                        noise=noise, steps=limits.fit_steps)
         if limits.n_samples:
             # the launch's impl is jit-static and comes from the
             # tenants' BOConfig.kernel_impl; the cohort default ("xla")
@@ -721,37 +711,36 @@ class SearchService:
         (the schedule length is part of the bucket key), possibly at
         different observation pads — re-pad to the common maximum
         (``_pad_stack_obs``'s exactness contract) and gather each
-        lane's rows, preserving the group's owner order."""
+        lane's rows, preserving the group's owner order. Each bucket
+        stack moves to the host once and the gather runs there, as the
+        warm-start cache refresh does: one transfer per bucket instead
+        of a handful of small device ops per lane, and no per-lane
+        index into a lane-sharded array."""
         n_max = max(st.n_max for st, _ in entries)
-        padded: Dict[int, Tuple] = {}
-        rows: Dict[str, List[Any]] = {k: [] for k in (
-            "x", "y", "mask", "y_mean", "y_std", "ls", "sf", "chol",
-            "alpha", "cnt")}
+        fields = ("x", "y", "mask", "y_mean", "y_std", "log_lengthscales",
+                  "log_signal", "chol", "alpha", "counts")
+        host: Dict[int, Dict[str, np.ndarray]] = {}
+        rows: Dict[str, List[np.ndarray]] = {f: [] for f in fields}
         for st, ln in entries:
-            c = padded.get(id(st))
-            if c is None:
+            h = host.get(id(st))
+            if h is None:
+                h = {f: np.asarray(getattr(st, f)) for f in fields}
                 p = n_max - st.n_max
-                x, mask, chol, alpha = _pad_stack_obs(st, n_max)
-                y = jnp.pad(st.y, ((0, 0), (0, p))) if p else st.y
-                c = (x, y, mask, chol, alpha)
-                padded[id(st)] = c
-            x, y, mask, chol, alpha = c
-            rows["x"].append(x[ln])
-            rows["y"].append(y[ln])
-            rows["mask"].append(mask[ln])
-            rows["chol"].append(chol[ln])
-            rows["alpha"].append(alpha[ln])
-            rows["y_mean"].append(st.y_mean[ln])
-            rows["y_std"].append(st.y_std[ln])
-            rows["ls"].append(st.log_lengthscales[ln])
-            rows["sf"].append(st.log_signal[ln])
-            rows["cnt"].append(st.counts[ln])
-        return BatchedGP(
-            jnp.stack(rows["x"]), jnp.stack(rows["y"]),
-            jnp.stack(rows["mask"]), jnp.stack(rows["y_mean"]),
-            jnp.stack(rows["y_std"]), jnp.stack(rows["ls"]),
-            jnp.stack(rows["sf"]), noise, jnp.stack(rows["chol"]),
-            jnp.stack(rows["alpha"]), jnp.stack(rows["cnt"]))
+                if p:
+                    h["x"] = np.pad(h["x"], ((0, 0), (0, p), (0, 0)))
+                    for f in ("y", "mask", "alpha"):
+                        h[f] = np.pad(h[f], ((0, 0), (0, p)))
+                    chol = np.pad(h["chol"], ((0, 0), (0, p), (0, p)))
+                    idx = np.arange(st.n_max, n_max)
+                    chol[:, idx, idx] = 1.0
+                    h["chol"] = chol
+                host[id(st)] = h
+            for f in fields:
+                rows[f].append(h[f][ln])
+        g = {f: jnp.asarray(np.stack(rows[f])) for f in fields}
+        return BatchedGP(g["x"], g["y"], g["mask"], g["y_mean"],
+                         g["y_std"], g["log_lengthscales"], g["log_signal"],
+                         noise, g["chol"], g["alpha"], g["counts"])
 
     def _posterior_phase(self, sessions: List[_Session]
                          ) -> Dict[int, Dict[str, Dict]]:

@@ -1,6 +1,8 @@
 """Regression tests for the compile-once accounting itself: per-name
 watcher snapshots (late-registered twins count) and strict dynamic
 launch registration."""
+import os
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -85,3 +87,32 @@ def test_watcher_immune_to_other_launches_base(scratch_names):
     compile_stats.register_launch("cs_test_cold", cold)
     cold(jnp.ones((2,)))
     assert watcher.misses() == 1
+
+
+def test_launch_without_jit_cache_raises(scratch_names):
+    """A tracked launch with no jit cache cannot be counted: the
+    accounting refuses it instead of reading zero compiles forever."""
+    scratch_names.append("cs_test_plain_fn")
+    compile_stats.register_launch("cs_test_plain_fn", lambda x: x)
+    with pytest.raises(TypeError, match="no jit cache"):
+        compile_stats.CompileWatcher()
+
+
+def test_compile_cache_dir_env_wins_else_checkout(monkeypatch, tmp_path):
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_stats.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == \
+            saved["jax_compilation_cache_dir"]
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__)))
+        want = os.path.join(checkout, ".jax_cache")
+        assert compile_stats.use_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)

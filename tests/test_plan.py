@@ -456,3 +456,20 @@ def test_enumerate_buckets_walks_both_fit_rungs():
                  if b.kind == "fit"}
     warm_live = next(b for b in live.buckets if b.key[1] == 16)
     assert planner.launch_signature(warm_live) not in cold_sigs
+
+
+def test_ehvi_vocabulary_walks_reachable_candidates_and_moo_lanes():
+    """EHVI buckets span only the remaining-candidate counts a session
+    of at most ``max_obs`` observations can score, and a lane ladder up
+    to ``max_moo_sessions`` rather than the model-lane bound — and the
+    closure analysis still finds every live EHVI signature inside."""
+    from repro.analysis.vocab_closure import check_closure
+    limits = CohortLimits(d=3, q_grid=40, max_obs=9, max_lanes=64,
+                          n_mc=(16,), n_objectives=(2, 3),
+                          max_ehvi_boxes=64, max_moo_sessions=3)
+    planner = StepPlanner()
+    ehvi = [b for b in planner.enumerate_buckets(limits)
+            if b.kind == "ehvi"]
+    assert {b.pads["q_pad"] for b in ehvi} == {32, 40}
+    assert {b.pads["l_pad"] for b in ehvi} == {1, 2, 4}
+    assert check_closure(limits=limits, shard_sizes=(1, 2)) == []
