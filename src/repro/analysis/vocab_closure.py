@@ -68,8 +68,8 @@ def iter_live_plans(planner, limits) -> Iterable:
     posterior/sample/loo buckets, every remaining-candidate count a
     session holding at most ``max_obs`` observations can score and every
     front in ``_ehvi_fronts`` for EHVI buckets (lane counts thinned to
-    {1, 2, max} there, capped at the kind's lane bound — the lane axis
-    rounds identically across kinds)."""
+    {1, 2, max} there — the lane axis rounds identically across
+    kinds)."""
     from repro.core.plan import (EhviQuery, FitQuery, LooSampleQuery,
                                  PosteriorQuery, SampleQuery)
     rng = np.random.default_rng(7)
@@ -111,8 +111,6 @@ def iter_live_plans(planner, limits) -> Iterable:
                                   np.zeros((n,), np.float32),
                                   noise, steps)] * lanes)
     fronts = _ehvi_fronts(limits, rng)
-    moo = limits.max_moo_sessions or limits.max_lanes
-    moo_lanes = sorted({1, min(2, moo), moo})
     for n_obj in limits.n_objectives:
         for s in limits.n_mc:
             for q in range(max(1, qg - limits.max_obs), qg + 1):
@@ -120,7 +118,7 @@ def iter_live_plans(planner, limits) -> Iterable:
                 for observed, ref in fronts:
                     if observed.shape[-1] != n_obj:
                         continue
-                    for lanes in moo_lanes:
+                    for lanes in thin_lanes:
                         yield planner.plan([EhviQuery(
                             samples=None, observed=observed, ref=ref,
                             mu=(row,) * n_obj, var=(row,) * n_obj,

@@ -230,11 +230,7 @@ class CohortLimits:
     fixed noise levels the (jit-static) fit launches will see.
     ``max_ehvi_boxes`` bounds the box-decomposition size of any front
     (2-objective fronts decompose into at most ``front+1`` staircase
-    boxes; n>=3 fronts grow faster and dominate the vocabulary).
-    ``max_moo_sessions`` bounds the EHVI launch's lane axis, one lane
-    per multi-objective session of a step (``None``: ``max_lanes``) —
-    in a mixed cohort it is far below the model-lane bound, and the
-    EHVI kind is the largest share of the vocabulary."""
+    boxes; n>=3 fronts grow faster and dominate the vocabulary)."""
     d: int
     q_grid: int
     max_obs: int
@@ -246,7 +242,6 @@ class CohortLimits:
     noises: Tuple[float, ...] = (0.1,)
     fit_steps: int = 120
     fit_warm_steps: int = 16
-    max_moo_sessions: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -488,7 +483,9 @@ class StepPlanner:
             rungs.add(int(limits.fit_warm_steps))
         return sorted(rungs)
 
-    def enumerate_buckets(self, limits: CohortLimits) -> List[Bucket]:
+    def enumerate_buckets(self, limits: CohortLimits,
+                          ehvi_lanes: Optional[int] = None
+                          ) -> List[Bucket]:
         """Walk the CLOSED launch-shape vocabulary a cohort bounded by
         ``limits`` can produce — one ``Bucket`` (empty ``indices``) per
         distinct jitted launch shape, keys stated at their padded values
@@ -503,10 +500,11 @@ class StepPlanner:
         buckets) and the sample count; LOO launches vary (n_pad, l_pad)
         per sample count; EHVI launches vary the candidate bucket (the
         remaining-candidate set shrinks every iteration), the box-axis
-        pad, and the MOO lane pad (up to ``max_moo_sessions``) per
-        (n_obj, n_mc); fit launches vary
-        (n_pad, m_pad) per (noise, steps-rung) — the warm and cold
-        schedule lengths from ``fit_step_rungs``."""
+        pad, and the MOO lane pad — one lane per multi-objective session
+        of a step, so up to ``ehvi_lanes`` (default ``max_lanes``) — per
+        (n_obj, n_mc); fit launches vary (n_pad, m_pad) per (noise,
+        steps-rung) — the warm and cold schedule lengths from
+        ``fit_step_rungs``."""
         out: List[Bucket] = []
         obs = self._obs_pads(limits.max_obs)
         lanes = self._lane_pads(limits.max_lanes)
@@ -528,8 +526,7 @@ class StepPlanner:
                     out.append(Bucket("loo", (s, n_pad), (),
                                       {"n_pad": n_pad, "l_pad": l_pad,
                                        "lanes": l_pad}))
-        moo_lanes = self._lane_pads(limits.max_moo_sessions
-                                    or limits.max_lanes)
+        moo_lanes = self._lane_pads(ehvi_lanes or limits.max_lanes)
         for n_obj in limits.n_objectives:
             for s in limits.n_mc:
                 for q_pad in self.ehvi_grid_pads(limits):

@@ -74,7 +74,8 @@ from repro.core.bo import (KEY_PURPOSE_MOO_EHVI, KEY_PURPOSE_RGPE, BOConfig,
                            _model_posteriors_augmented, _should_stop_early,
                            _target_runs, derive_key)
 from repro.core.encoding import SearchSpace
-from repro.core.gp import GP, BatchedGP, GPParams, batched_posterior
+from repro.core.gp import (GP, BatchedGP, GPParams, _pad_stack_obs,
+                           batched_posterior)
 from repro.core.repository import Repository
 from repro.core.rgpe import WeightJob, mix_weighted
 from repro.kernels.ranking_loss import ranking_loss_launch_fn
@@ -451,7 +452,10 @@ class SearchService:
         like an observation axis. Returns ``{"buckets", "compiles"}``
         and folds both into ``stats``."""
         watch = CompileWatcher()
-        buckets = self.planner.enumerate_buckets(limits)
+        # a step's EHVI launch has one lane per multi-objective session,
+        # and at most ``slots`` sessions are active
+        buckets = self.planner.enumerate_buckets(
+            limits, ehvi_lanes=min(self.slots, limits.max_lanes))
         for bucket in buckets:
             queries, prep = self._dummy_bucket(bucket, limits)
             self.plan_executor.execute(StepPlan(
@@ -711,36 +715,37 @@ class SearchService:
         (the schedule length is part of the bucket key), possibly at
         different observation pads — re-pad to the common maximum
         (``_pad_stack_obs``'s exactness contract) and gather each
-        lane's rows, preserving the group's owner order. Each bucket
-        stack moves to the host once and the gather runs there, as the
-        warm-start cache refresh does: one transfer per bucket instead
-        of a handful of small device ops per lane, and no per-lane
-        index into a lane-sharded array."""
+        lane's rows, preserving the group's owner order."""
         n_max = max(st.n_max for st, _ in entries)
-        fields = ("x", "y", "mask", "y_mean", "y_std", "log_lengthscales",
-                  "log_signal", "chol", "alpha", "counts")
-        host: Dict[int, Dict[str, np.ndarray]] = {}
-        rows: Dict[str, List[np.ndarray]] = {f: [] for f in fields}
+        padded: Dict[int, Tuple] = {}
+        rows: Dict[str, List[Any]] = {k: [] for k in (
+            "x", "y", "mask", "y_mean", "y_std", "ls", "sf", "chol",
+            "alpha", "cnt")}
         for st, ln in entries:
-            h = host.get(id(st))
-            if h is None:
-                h = {f: np.asarray(getattr(st, f)) for f in fields}
+            c = padded.get(id(st))
+            if c is None:
                 p = n_max - st.n_max
-                if p:
-                    h["x"] = np.pad(h["x"], ((0, 0), (0, p), (0, 0)))
-                    for f in ("y", "mask", "alpha"):
-                        h[f] = np.pad(h[f], ((0, 0), (0, p)))
-                    chol = np.pad(h["chol"], ((0, 0), (0, p), (0, p)))
-                    idx = np.arange(st.n_max, n_max)
-                    chol[:, idx, idx] = 1.0
-                    h["chol"] = chol
-                host[id(st)] = h
-            for f in fields:
-                rows[f].append(h[f][ln])
-        g = {f: jnp.asarray(np.stack(rows[f])) for f in fields}
-        return BatchedGP(g["x"], g["y"], g["mask"], g["y_mean"],
-                         g["y_std"], g["log_lengthscales"], g["log_signal"],
-                         noise, g["chol"], g["alpha"], g["counts"])
+                x, mask, chol, alpha = _pad_stack_obs(st, n_max)
+                y = jnp.pad(st.y, ((0, 0), (0, p))) if p else st.y
+                c = (x, y, mask, chol, alpha)
+                padded[id(st)] = c
+            x, y, mask, chol, alpha = c
+            rows["x"].append(x[ln])
+            rows["y"].append(y[ln])
+            rows["mask"].append(mask[ln])
+            rows["chol"].append(chol[ln])
+            rows["alpha"].append(alpha[ln])
+            rows["y_mean"].append(st.y_mean[ln])
+            rows["y_std"].append(st.y_std[ln])
+            rows["ls"].append(st.log_lengthscales[ln])
+            rows["sf"].append(st.log_signal[ln])
+            rows["cnt"].append(st.counts[ln])
+        return BatchedGP(
+            jnp.stack(rows["x"]), jnp.stack(rows["y"]),
+            jnp.stack(rows["mask"]), jnp.stack(rows["y_mean"]),
+            jnp.stack(rows["y_std"]), jnp.stack(rows["ls"]),
+            jnp.stack(rows["sf"]), noise, jnp.stack(rows["chol"]),
+            jnp.stack(rows["alpha"]), jnp.stack(rows["cnt"]))
 
     def _posterior_phase(self, sessions: List[_Session]
                          ) -> Dict[int, Dict[str, Dict]]:

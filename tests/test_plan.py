@@ -461,15 +461,17 @@ def test_enumerate_buckets_walks_both_fit_rungs():
 def test_ehvi_vocabulary_walks_reachable_candidates_and_moo_lanes():
     """EHVI buckets span only the remaining-candidate counts a session
     of at most ``max_obs`` observations can score, and a lane ladder up
-    to ``max_moo_sessions`` rather than the model-lane bound — and the
-    closure analysis still finds every live EHVI signature inside."""
+    to the ``ehvi_lanes`` bound (default: the model-lane bound) — and
+    the closure analysis still finds every live EHVI signature inside."""
     from repro.analysis.vocab_closure import check_closure
     limits = CohortLimits(d=3, q_grid=40, max_obs=9, max_lanes=64,
                           n_mc=(16,), n_objectives=(2, 3),
-                          max_ehvi_boxes=64, max_moo_sessions=3)
+                          max_ehvi_boxes=64)
     planner = StepPlanner()
-    ehvi = [b for b in planner.enumerate_buckets(limits)
+    ehvi = [b for b in planner.enumerate_buckets(limits, ehvi_lanes=3)
             if b.kind == "ehvi"]
     assert {b.pads["q_pad"] for b in ehvi} == {32, 40}
     assert {b.pads["l_pad"] for b in ehvi} == {1, 2, 4}
+    assert {b.pads["l_pad"] for b in planner.enumerate_buckets(limits)
+            if b.kind == "ehvi"} == {1, 2, 4, 8, 16, 32, 64}
     assert check_closure(limits=limits, shard_sizes=(1, 2)) == []
