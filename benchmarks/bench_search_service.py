@@ -439,7 +439,7 @@ def _fused_kernel_numbers() -> None:
 
     from repro.core.acquisition import expected_improvement
     from repro.core.gp import _batched_posterior
-    from repro.kernels.fused_posterior.ops import _fused_launch
+    from repro.kernels.fused_posterior.ops import _fused_posterior_launch
 
     m, n, q, d = 16, 64, 512, 7
     rng = np.random.default_rng(0)
@@ -458,12 +458,12 @@ def _fused_kernel_numbers() -> None:
         mu, var = _batched_posterior(ls, sf, x, mask, chol, alpha, xq)
         return expected_improvement(mu, var, 0.0)
 
-    _fused_launch(*args, impl="xla")[2].block_until_ready()
+    _fused_posterior_launch(*args, impl="xla")[2].block_until_ready()
     vmapped().block_until_ready()
     reps = 20
     t0 = time.time()
     for _ in range(reps):
-        _fused_launch(*args, impl="xla")[2].block_until_ready()
+        _fused_posterior_launch(*args, impl="xla")[2].block_until_ready()
     fused_s = (time.time() - t0) / reps
     t0 = time.time()
     for _ in range(reps):
@@ -591,6 +591,13 @@ def _fused_fit_numbers() -> None:
            f"{cold_s / warm_s:.2f}")
 
 
+def _plan_host_s(stats) -> float:
+    """Host seconds the plan layer's spans (``StepPlanner.plan`` and
+    the executor's pack / launch / unpack / scatter) took, self time."""
+    return sum(stats.get(f"span_s.{n}", 0.0)
+               for n in ("plan", "pack", "launch", "unpack", "scatter"))
+
+
 def steady_state() -> None:
     """Compile-once serving (the ISSUE-6 acceptance scenario): per-step
     latency of a churning mixed SO + 2-objective + 3-objective cohort
@@ -687,10 +694,11 @@ def steady_state() -> None:
            f"{pre['buckets']}buckets_{pre['compiles']}compiles")
     C.emit("search_service_steady_misses", 0.0,
            str(warm.stats["plan_compile_misses"]))
-    # the fit round's wall per service step, annotated with how the
-    # cohort's fit lanes split between the warm refine and cold rungs
-    C.emit("search_service_steady_fit_wall",
-           warm.stats["fit_wall_s"] * 1e6 / steps,
+    # the plan layer's host time per service step (its spans' self
+    # time), annotated with how the cohort's fit lanes split between
+    # the warm refine and cold rungs
+    C.emit("search_service_steady_plan_host",
+           _plan_host_s(warm.stats) * 1e6 / steps,
            f"warm{warm.stats['fit_warm_lanes']}"
            f"_cold{warm.stats['fit_cold_lanes']}")
     _fused_kernel_numbers()
@@ -752,12 +760,13 @@ def mesh_scaling() -> None:
            f"{base_step / sh_step:.2f}x_over_{MESH_N}dev")
     C.emit("search_service_mesh_misses", 0.0,
            str(sh_svc.stats["plan_compile_misses"]))
-    # per-leg dispatch wall split (satellite of the wall counters): how
-    # much of the warm step the fit leg still claims on each path
+    # the plan layer's host time on each path (its spans' self time),
+    # with the share of it spent inside the jitted launch calls
     for tag, svc in (("mesh1", base_svc), (f"mesh{MESH_N}", sh_svc)):
         s = svc.stats
-        C.emit(f"search_service_{tag}_fit_wall", s["fit_wall_s"] * 1e6,
-               f"plan_wall={s['plan_wall_s']:.3f}s")
+        C.emit(f"search_service_{tag}_plan_host",
+               _plan_host_s(s) * 1e6,
+               f"launch={s.get('span_s.launch', 0.0):.3f}s")
 
 
 def main() -> None:

@@ -59,7 +59,8 @@ from benchmarks.common import random_profiled_repo  # noqa: E402
 from repro.core import (BOConfig, Constraint, Objective,  # noqa: E402
                         scout_search_space)
 from repro.core.plan import CohortLimits, PlanExecutor  # noqa: E402
-from repro.launch.compile_stats import use_compile_cache  # noqa: E402
+from repro.launch.compile_stats import (CompileWatcher,  # noqa: E402
+                                        use_compile_cache)
 from repro.serve.search_service import (SearchRequest,  # noqa: E402
                                         SearchService)
 from repro.simdata import make_emulator  # noqa: E402
@@ -153,23 +154,27 @@ def cohort_limits(space) -> CohortLimits:
 
 
 class RecordingExecutor(PlanExecutor):
-    """The default executor, counting the impl each bucket ran and the
-    host wall of each plan by its first bucket's kind (precompile runs
-    one bucket per plan, compiles included)."""
+    """The default executor, counting the impl each bucket ran."""
 
     def __init__(self, **kw):
         super().__init__(**kw)
         self.impls = collections.defaultdict(collections.Counter)
-        self.walls = collections.Counter()
 
     def execute(self, plan, **kw):
         for b in plan.buckets:
             self.impls[b.kind][self.bucket_impl(b, kw.get("impl"))] += 1
-        t0 = time.perf_counter()
-        out = super().execute(plan, **kw)
-        if plan.buckets:
-            self.walls[plan.buckets[0].kind] += time.perf_counter() - t0
-        return out
+        return super().execute(plan, **kw)
+
+
+def log_spans(stats, label: str, since=None) -> None:
+    """The service's span totals (self seconds per span) and the programs
+    each span built, as ``stats`` holds them (less ``since``)."""
+    since = since or {}
+    for prefix in ("span_s.", "compiles."):
+        got = {k[len(prefix):]: v - since.get(k, 0)
+               for k, v in sorted(stats.items()) if k.startswith(prefix)}
+        log(f"{label} {prefix.rstrip('.')}: "
+            + ", ".join(f"{k} {v:.6g}" for k, v in got.items() if v))
 
 
 class RecordingService(SearchService):
@@ -425,12 +430,16 @@ def serve_one_chip(seed: int) -> None:
     log(f"precompile: {time.perf_counter() - t0:.3f} s, "
         f"{pre['buckets']} buckets, {pre['compiles']} compiles")
     for kind, c in sorted(executor.impls.items()):
-        log(f"precompile impl {kind}: {dict(c)}, "
-            f"wall {executor.walls[kind]:.3f} s")
+        log(f"precompile impl {kind}: {dict(c)}")
+    log_spans(svc.stats, "precompile")
     if executor.impls["fit"]["pallas"] == 0:
         raise AssertionError("no fit bucket resolved to the Pallas kernel")
     executor.impls.clear()
 
+    stats0 = dict(svc.stats)
+    # support fits are unpadded and outside the precompiled vocabulary:
+    # counted apart from the plan launches, which must not compile
+    watch = CompileWatcher()
     walls, checked, flat = [], 0, 0
     for step in range(STEPS):
         t0 = time.perf_counter()
@@ -445,6 +454,7 @@ def serve_one_chip(seed: int) -> None:
     for kind, c in sorted(executor.impls.items()):
         log(f"serving impl {kind}: {dict(c)}")
     log(f"steps: {len(walls)}, p50 step wall {np.median(walls):.6f} s")
+    log_spans(svc.stats, f"{len(walls)} steps", since=stats0)
     log(f"float64 EI decisions checked: {checked}, of which {flat} "
         f"had best EI <= {EI_ATOL} (any pick passes)")
     if checked != SO_TENANTS * STEPS:
@@ -453,11 +463,13 @@ def serve_one_chip(seed: int) -> None:
     if flat > MAX_FLAT:
         raise AssertionError(f"{flat} decisions had an all-but-zero EI, "
                              f"more than {MAX_FLAT}")
-    misses = svc.stats["plan_compile_misses"]
-    log(f"plan_compile_misses: {misses}")
-    if misses:
-        raise AssertionError(f"{misses} plan launches compiled after "
-                             f"precompile")
+    compiled = watch.delta()
+    support = compiled.pop("support_fit", 0)
+    log(f"plan_compile_misses: {svc.stats['plan_compile_misses']} "
+        f"({support} support fits)")
+    if compiled:
+        raise AssertionError(f"plan launches compiled after precompile: "
+                             f"{compiled}")
     iters = [len(s.observations) - BO.n_init for s in svc.active.values()]
     done = svc.collect()
     log(f"collect: {len(done)} finished, {len(svc.active)} active, "

@@ -355,7 +355,8 @@ def _fused_posterior_spec() -> LaunchSpec:
         valid_outs=(valid, valid, valid),
         arg_names=("log_ls", "log_sf", "x", "mask", "chol", "alpha",
                    "xq", "best"),
-        twins=(fp_ops._fused_launch, fp_ops._fused_launch_donated))
+        twins=(fp_ops._fused_posterior_launch,
+               fp_ops._fused_posterior_launch_donated))
 
 
 def _fused_fit_spec() -> LaunchSpec:

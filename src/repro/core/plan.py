@@ -61,7 +61,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -71,6 +70,7 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.distributed import auto_axes, mesh_axis_size, shard_map
 from repro.kernels.routing import resolve_impl
+from repro.launch.spans import span
 
 from .acquisition import (EHVI_BOX_CHUNK, _ehvi_box_launch,
                           _ehvi_box_launch_donated, expected_improvement,
@@ -372,16 +372,17 @@ class StepPlanner:
         (``_pads_ehvi`` must know each front's box count to fix
         ``k_pad``), which is computed once per query on the host and
         carried to the executor via ``StepPlan.prep``."""
-        groups: Dict[Tuple[str, Tuple], List[int]] = {}
-        for i, query in enumerate(queries):
-            groups.setdefault(self.bucket_key(query), []).append(i)
-        prep: Dict[int, Any] = {}
-        buckets = []
-        for (kind, key), idxs in groups.items():
-            pads = getattr(self, f"_pads_{kind}")(
-                key, [queries[i] for i in idxs], idxs, prep)
-            buckets.append(Bucket(kind, key, tuple(idxs), pads))
-        return StepPlan(list(queries), buckets, prep)
+        with span("plan"):
+            groups: Dict[Tuple[str, Tuple], List[int]] = {}
+            for i, query in enumerate(queries):
+                groups.setdefault(self.bucket_key(query), []).append(i)
+            prep: Dict[int, Any] = {}
+            buckets = []
+            for (kind, key), idxs in groups.items():
+                pads = getattr(self, f"_pads_{kind}")(
+                    key, [queries[i] for i in idxs], idxs, prep)
+                buckets.append(Bucket(kind, key, tuple(idxs), pads))
+            return StepPlan(list(queries), buckets, prep)
 
     def _pads_posterior(self, key, queries, idxs, prep) -> Dict[str, int]:
         lanes = sum(q.stack.m for q in queries)
@@ -587,14 +588,13 @@ class StepPlanner:
 
 
 def _count(counters: Optional[dict], kind: str, queries: int,
-           lanes: int, wall_s: float = 0.0) -> None:
+           lanes: int) -> None:
     if counters is None:
         return
     c = counters.setdefault(kind, {})
     c["launches"] = c.get("launches", 0) + 1
     c["queries"] = c.get("queries", 0) + queries
     c["lanes"] = c.get("lanes", 0) + lanes
-    c["wall_s"] = c.get("wall_s", 0.0) + wall_s
 
 
 def flatten_counters(nested: dict, counters: Optional[dict],
@@ -855,24 +855,19 @@ class PlanExecutor:
         results: List[Any] = [None] * len(plan.queries)
         for bucket in plan.buckets:
             queries = [plan.queries[i] for i in bucket.indices]
-            # host-side dispatch wall per bucket kind: includes lane
-            # assembly + launch dispatch but NOT device completion (jax
-            # dispatch is async) — a relative hotness signal across
-            # kinds, not a device-time profile
-            t0 = time.perf_counter()
+            # each kind's launch splits into pack / launch / unpack spans
             out = getattr(self, f"_exec_{bucket.kind}")(
                 bucket, queries, plan, impl)
-            wall = time.perf_counter() - t0
             for i, r in zip(bucket.indices, out):
                 results[i] = r
             _count(counters, bucket.kind, len(queries),
                    bucket.pads.get("m_pad",
                                    bucket.pads.get("l_pad",
-                                                   bucket.pads["lanes"])),
-                   wall)
-        for query, result in zip(plan.queries, results):
-            if callable(query.owner):
-                query.owner(result)
+                                                   bucket.pads["lanes"])))
+        with span("scatter"):
+            for query, result in zip(plan.queries, results):
+                if callable(query.owner):
+                    query.owner(result)
         return results
 
     # -- per-kind launches ---------------------------------------------------
@@ -925,110 +920,155 @@ class PlanExecutor:
     def _exec_posterior(self, bucket, queries, plan, impl):
         q, d = bucket.key
         n_pad, m_pad = bucket.pads["n_pad"], bucket.pads["m_pad"]
-        parts = self._fresh_parts(
-            queries, self._stack_parts(queries, n_pad, q, d))
-        r_impl = self.bucket_impl(bucket, impl)
-        if self.fused_posterior:
-            from repro.kernels.fused_posterior import fused_launch_fn
-            # per-lane incumbents; lanes without an EI head get 0.0 (the
-            # EI row is computed either way — shape stability — and
-            # simply not returned for those queries)
-            best = jnp.concatenate([
-                jnp.full((query.stack.m,),
-                         0.0 if query.best is None else float(query.best),
-                         jnp.float32) for query in queries])
-            parts = self._pad_lanes(parts + [best], m_pad)
-            launch = self._launch("fused_posterior",
-                                  fused_launch_fn(donate=False),
-                                  fused_launch_fn(donate=True))
-            mu, var, ei = launch(*parts, impl=r_impl)
-        else:
-            parts = self._pad_lanes(parts, m_pad)
-            launch = self._launch("posterior", _batched_posterior,
-                                  _batched_posterior_donated)
-            mu, var = launch(*parts, impl=r_impl)
-            ei = None
-        out, off = [], 0
-        for query in queries:
-            rows = slice(off, off + query.stack.m)
-            if query.best is None:
-                out.append((mu[rows], var[rows]))
-            elif ei is not None:
-                out.append((mu[rows], var[rows], ei[rows]))
+        with span("pack", kind="posterior"):
+            parts = self._fresh_parts(
+                queries, self._stack_parts(queries, n_pad, q, d))
+            r_impl = self.bucket_impl(bucket, impl)
+            if self.fused_posterior:
+                from repro.kernels.fused_posterior import fused_launch_fn
+                # per-lane incumbents; lanes without an EI head get 0.0
+                # (the EI row is computed either way — shape stability —
+                # and simply not returned for those queries)
+                best = jnp.concatenate([
+                    jnp.full((query.stack.m,),
+                             0.0 if query.best is None
+                             else float(query.best),
+                             jnp.float32) for query in queries])
+                parts = self._pad_lanes(parts + [best], m_pad)
+                launch = self._launch("fused_posterior",
+                                      fused_launch_fn(donate=False),
+                                      fused_launch_fn(donate=True))
             else:
-                out.append((mu[rows], var[rows], expected_improvement(
-                    mu[rows], var[rows], float(query.best))))
-            off += query.stack.m
+                parts = self._pad_lanes(parts, m_pad)
+                launch = self._launch("posterior", _batched_posterior,
+                                      _batched_posterior_donated)
+        with span("launch", kind="posterior"):
+            res = launch(*parts, impl=r_impl)
+        mu, var = res[:2]
+        ei = res[2] if self.fused_posterior else None
+        with span("unpack", kind="posterior"):
+            out, off = [], 0
+            for query in queries:
+                rows = slice(off, off + query.stack.m)
+                if query.best is None:
+                    out.append((mu[rows], var[rows]))
+                elif ei is not None:
+                    out.append((mu[rows], var[rows], ei[rows]))
+                else:
+                    out.append((mu[rows], var[rows], expected_improvement(
+                        mu[rows], var[rows], float(query.best))))
+                off += query.stack.m
         return out
 
     def _exec_sample(self, bucket, queries, plan, impl):
         n_samples, q, d = bucket.key
         n_pad, q_pad, m_pad = (bucket.pads["n_pad"], bucket.pads["q_pad"],
                                bucket.pads["m_pad"])
-        parts = self._fresh_parts(
-            queries, self._stack_parts(queries, n_pad, q, d, q_pad=q_pad))
-        keys_cat = jnp.concatenate(
-            [jnp.asarray(query.keys) for query in queries])
-        # exact-shape draws (one dispatch for the bucket), THEN pad: the
-        # grid padding that keeps jit shapes stable across steps must
-        # never perturb a lane's PRNG stream
-        eps = jax.vmap(
-            lambda k: jax.random.normal(k, (n_samples, q)))(keys_cat)
-        if q_pad > q:
-            eps = jnp.pad(eps, ((0, 0), (0, 0), (0, q_pad - q)))
-        parts = self._pad_lanes(parts + [eps], m_pad)
-        r_impl = self.bucket_impl(bucket, impl)
-        launch = self._launch("sample", _batched_sample_launch,
-                              _batched_sample_launch_donated)
-        s = launch(*parts, impl=r_impl)
-        out, off = [], 0
-        for query in queries:
-            out.append(s[off:off + query.stack.m, :, :q])
-            off += query.stack.m
+        with span("pack", kind="sample"):
+            parts = self._fresh_parts(
+                queries,
+                self._stack_parts(queries, n_pad, q, d, q_pad=q_pad))
+            keys_cat = jnp.concatenate(
+                [jnp.asarray(query.keys) for query in queries])
+            # exact-shape draws (one dispatch for the bucket), THEN pad:
+            # the grid padding that keeps jit shapes stable across steps
+            # must never perturb a lane's PRNG stream
+            eps = jax.vmap(
+                lambda k: jax.random.normal(k, (n_samples, q)))(keys_cat)
+            if q_pad > q:
+                eps = jnp.pad(eps, ((0, 0), (0, 0), (0, q_pad - q)))
+            parts = self._pad_lanes(parts + [eps], m_pad)
+            r_impl = self.bucket_impl(bucket, impl)
+            launch = self._launch("sample", _batched_sample_launch,
+                                  _batched_sample_launch_donated)
+        with span("launch", kind="sample"):
+            s = launch(*parts, impl=r_impl)
+        with span("unpack", kind="sample"):
+            out, off = [], 0
+            for query in queries:
+                out.append(s[off:off + query.stack.m, :, :q])
+                off += query.stack.m
         return out
 
     def _exec_loo(self, bucket, queries, plan, impl):
         n_samples, n = bucket.key
         n_pad = bucket.pads["n_pad"]
         p = n_pad - n
-        chols, alphas, ys = [], [], []
-        for query in queries:
-            gp = query.gp
-            chol = jnp.pad(gp.chol, ((0, p), (0, p)))
+        with span("pack", kind="loo"):
+            chols, alphas, ys = [], [], []
+            for query in queries:
+                gp = query.gp
+                chol = jnp.pad(gp.chol, ((0, p), (0, p)))
+                if p:
+                    bump = jnp.concatenate([jnp.zeros((n,), jnp.float32),
+                                            jnp.ones((p,), jnp.float32)])
+                    chol = chol + jnp.diag(bump)
+                chols.append(chol)
+                alphas.append(jnp.pad(gp.alpha, (0, p)))
+                ys.append(jnp.pad(gp.y, (0, p)))
+            keys = jnp.stack([jnp.asarray(query.key) for query in queries])
+            eps = jax.vmap(
+                lambda k: jax.random.normal(k, (n_samples, n)))(keys)
             if p:
-                bump = jnp.concatenate([jnp.zeros((n,), jnp.float32),
-                                        jnp.ones((p,), jnp.float32)])
-                chol = chol + jnp.diag(bump)
-            chols.append(chol)
-            alphas.append(jnp.pad(gp.alpha, (0, p)))
-            ys.append(jnp.pad(gp.y, (0, p)))
-        keys = jnp.stack([jnp.asarray(query.key) for query in queries])
-        eps = jax.vmap(
-            lambda k: jax.random.normal(k, (n_samples, n)))(keys)
-        if p:
-            eps = jnp.pad(eps, ((0, 0), (0, 0), (0, p)))
-        parts = self._pad_lanes(
-            [jnp.stack(chols), jnp.stack(alphas), jnp.stack(ys), eps],
-            bucket.pads["l_pad"])
-        # every LOO part is stacked fresh above (jnp.stack always
-        # copies), so donation needs no single-query guard here
-        launch = self._launch("loo", _batched_loo_launch,
-                              _batched_loo_launch_donated)
-        s = launch(*parts)
-        return [s[j, :, :n] for j in range(len(queries))]
+                eps = jnp.pad(eps, ((0, 0), (0, 0), (0, p)))
+            parts = self._pad_lanes(
+                [jnp.stack(chols), jnp.stack(alphas), jnp.stack(ys), eps],
+                bucket.pads["l_pad"])
+            # every LOO part is stacked fresh above (jnp.stack always
+            # copies), so donation needs no single-query guard here
+            launch = self._launch("loo", _batched_loo_launch,
+                                  _batched_loo_launch_donated)
+        with span("launch", kind="loo"):
+            s = launch(*parts)
+        with span("unpack", kind="loo"):
+            return [s[j, :, :n] for j in range(len(queries))]
 
     def _exec_draw(self, bucket, queries, plan, impl):
         n_mc, _q = bucket.key
-        parts = [jnp.stack([jnp.asarray(getattr(query, f))
-                            for query in queries])
-                 for f in ("key", "mu", "var", "y_std", "y_mean")]
-        draws = _draw_launch(*parts, n_mc=n_mc)
-        return [draws[j] for j in range(len(queries))]
+        with span("pack", kind="draw"):
+            parts = [jnp.stack([jnp.asarray(getattr(query, f))
+                                for query in queries])
+                     for f in ("key", "mu", "var", "y_std", "y_mean")]
+        with span("launch", kind="draw"):
+            draws = _draw_launch(*parts, n_mc=n_mc)
+        with span("unpack", kind="draw"):
+            return [draws[j] for j in range(len(queries))]
 
     def _exec_ehvi(self, bucket, queries, plan, impl):
-        n_obj, s, q = bucket.key
-        k_pad, q_pad, l_pad = (bucket.pads["k_pad"], bucket.pads["q_pad"],
-                               bucket.pads["l_pad"])
+        if self.fused_ehvi:
+            return self._exec_ehvi_fused(bucket, queries, plan, impl)
+        _n_obj, s, q = bucket.key
+        q_pad, l_pad = bucket.pads["q_pad"], bucket.pads["l_pad"]
+        with span("pack", kind="ehvi"):
+            los, his, refs = self._ehvi_fronts(bucket, queries, plan)
+            ps = []
+            for query in queries:
+                samples = (query.samples if query.samples is not None
+                           else _materialise_ehvi_draws(query, s, q))
+                # +inf candidates gain nothing and are sliced off below
+                ps.append(np.stack(
+                    [np.pad(np.asarray(sm, np.float32),
+                            ((0, 0), (0, q_pad - q)),
+                            constant_values=np.inf)
+                     for sm in samples]))
+            parts = [jnp.asarray(np.stack(a).astype(np.float32))
+                     for a in (los, his, refs, ps)]
+            parts = self._pad_lanes(parts, l_pad)
+            # all four parts are host-assembled fresh every step
+            # (np.stack -> device transfer), so donation is
+            # unconditionally alias-safe
+            launch = self._launch("ehvi", _ehvi_box_launch,
+                                  _ehvi_box_launch_donated)
+        with span("launch", kind="ehvi"):
+            out = launch(*parts)
+        with span("unpack", kind="ehvi"):
+            return [np.asarray(out[j])[:q] for j in range(len(queries))]
+
+    @staticmethod
+    def _ehvi_fronts(bucket, queries, plan):
+        """Each lane's box decomposition (from the plan's ``prep``),
+        padded to the bucket's ``k_pad``, and its reference point."""
+        k_pad = bucket.pads["k_pad"]
         los, his, refs = [], [], []
         for i, query in zip(bucket.indices, queries):
             lo, hi = plan.prep[i]
@@ -1039,29 +1079,9 @@ class PlanExecutor:
             his.append(np.pad(hi, ((0, pad), (0, 0)),
                               constant_values=np.inf))
             refs.append(np.asarray(query.ref, np.float32))
-        if self.fused_ehvi:
-            return self._exec_ehvi_fused(bucket, queries, los, his, refs,
-                                         impl)
-        ps = []
-        for query in queries:
-            samples = (query.samples if query.samples is not None
-                       else _materialise_ehvi_draws(query, s, q))
-            # +inf candidates gain nothing and are sliced off below
-            ps.append(np.stack(
-                [np.pad(np.asarray(sm, np.float32),
-                        ((0, 0), (0, q_pad - q)), constant_values=np.inf)
-                 for sm in samples]))
-        parts = [jnp.asarray(np.stack(a).astype(np.float32))
-                 for a in (los, his, refs, ps)]
-        parts = self._pad_lanes(parts, l_pad)
-        # all four parts are host-assembled fresh every step (np.stack ->
-        # device transfer), so donation is unconditionally alias-safe
-        launch = self._launch("ehvi", _ehvi_box_launch,
-                              _ehvi_box_launch_donated)
-        out = launch(*parts)
-        return [np.asarray(out[j])[:q] for j in range(len(queries))]
+        return los, his, refs
 
-    def _exec_ehvi_fused(self, bucket, queries, los, his, refs, impl):
+    def _exec_ehvi_fused(self, bucket, queries, plan, impl):
         """One ``kernels.fused_ehvi`` launch for the bucket: the draw
         affine runs inside the kernel, so the (L, D, S, q) raw-scale
         draw tensor never round-trips through HBM. Sample-form queries
@@ -1069,58 +1089,62 @@ class PlanExecutor:
         the kernel then reproduces their precomputed draws exactly."""
         from repro.kernels.fused_ehvi import fused_ehvi_launch_fn
         n_obj, s, q = bucket.key
-        k_pad, q_pad, l_pad = (bucket.pads["k_pad"], bucket.pads["q_pad"],
-                               bucket.pads["l_pad"])
+        q_pad, l_pad = bucket.pads["q_pad"], bucket.pads["l_pad"]
         pq = q_pad - q
-        # exact-shape draws for every posterior-form lane of the bucket
-        # in ONE dispatch — normal(key, (n_mc, q)) per objective, the
-        # identical stream _draw_launch and the per-session loop consume
-        key_rows = [jnp.asarray(k) for query in queries
-                    if query.samples is None for k in query.keys]
-        eps_all = (jax.vmap(lambda k: jax.random.normal(k, (s, q)))(
-            jnp.stack(key_rows)) if key_rows else None)
-        mus, vars_, yms, yss, epss = [], [], [], [], []
-        off = 0
-        for query in queries:
-            if query.samples is None:
-                # padded candidates carry mu = +inf / var = 0: their
-                # draws land at +inf and gain nothing
-                mus.append(np.pad(
-                    np.stack([np.asarray(m, np.float32)
-                              for m in query.mu]),
-                    ((0, 0), (0, pq)), constant_values=np.inf))
-                vars_.append(np.pad(
-                    np.stack([np.asarray(v, np.float32)
-                              for v in query.var]), ((0, 0), (0, pq))))
-                yms.append(np.asarray(query.y_mean, np.float32))
-                yss.append(np.asarray(query.y_std, np.float32))
-                eps = eps_all[off:off + n_obj]
-                off += n_obj
-                if pq:
-                    eps = jnp.pad(eps, ((0, 0), (0, 0), (0, pq)))
-                epss.append(eps)
-            else:
-                # identity affine; the +inf pad rides on the samples
-                mus.append(np.zeros((n_obj, q_pad), np.float32))
-                vars_.append(np.ones((n_obj, q_pad), np.float32))
-                yms.append(np.zeros((n_obj,), np.float32))
-                yss.append(np.ones((n_obj,), np.float32))
-                epss.append(jnp.asarray(np.stack(
-                    [np.pad(np.asarray(sm, np.float32),
-                            ((0, 0), (0, pq)), constant_values=np.inf)
-                     for sm in query.samples])))
-        parts = [jnp.asarray(np.stack(a).astype(np.float32))
-                 for a in (los, his, refs, mus, vars_, yms, yss)]
-        parts.append(jnp.stack(epss))
-        parts = self._pad_lanes(parts, l_pad)
-        r_impl = self.bucket_impl(bucket, impl)
-        # every argument is rebuilt per step (host-assembled stacks,
-        # fresh draws), so the donating twin is alias-safe here too
-        launch = self._launch("fused_ehvi",
-                              fused_ehvi_launch_fn(donate=False),
-                              fused_ehvi_launch_fn(donate=True))
-        out = launch(*parts, impl=r_impl)
-        return [np.asarray(out[j])[:q] for j in range(len(queries))]
+        with span("pack", kind="ehvi"):
+            los, his, refs = self._ehvi_fronts(bucket, queries, plan)
+            # exact-shape draws for every posterior-form lane of the
+            # bucket in ONE dispatch — normal(key, (n_mc, q)) per
+            # objective, the identical stream _draw_launch and the
+            # per-session loop consume
+            key_rows = [jnp.asarray(k) for query in queries
+                        if query.samples is None for k in query.keys]
+            eps_all = (jax.vmap(lambda k: jax.random.normal(k, (s, q)))(
+                jnp.stack(key_rows)) if key_rows else None)
+            mus, vars_, yms, yss, epss = [], [], [], [], []
+            off = 0
+            for query in queries:
+                if query.samples is None:
+                    # padded candidates carry mu = +inf / var = 0: their
+                    # draws land at +inf and gain nothing
+                    mus.append(np.pad(
+                        np.stack([np.asarray(m, np.float32)
+                                  for m in query.mu]),
+                        ((0, 0), (0, pq)), constant_values=np.inf))
+                    vars_.append(np.pad(
+                        np.stack([np.asarray(v, np.float32)
+                                  for v in query.var]), ((0, 0), (0, pq))))
+                    yms.append(np.asarray(query.y_mean, np.float32))
+                    yss.append(np.asarray(query.y_std, np.float32))
+                    eps = eps_all[off:off + n_obj]
+                    off += n_obj
+                    if pq:
+                        eps = jnp.pad(eps, ((0, 0), (0, 0), (0, pq)))
+                    epss.append(eps)
+                else:
+                    # identity affine; the +inf pad rides on the samples
+                    mus.append(np.zeros((n_obj, q_pad), np.float32))
+                    vars_.append(np.ones((n_obj, q_pad), np.float32))
+                    yms.append(np.zeros((n_obj,), np.float32))
+                    yss.append(np.ones((n_obj,), np.float32))
+                    epss.append(jnp.asarray(np.stack(
+                        [np.pad(np.asarray(sm, np.float32),
+                                ((0, 0), (0, pq)), constant_values=np.inf)
+                         for sm in query.samples])))
+            parts = [jnp.asarray(np.stack(a).astype(np.float32))
+                     for a in (los, his, refs, mus, vars_, yms, yss)]
+            parts.append(jnp.stack(epss))
+            parts = self._pad_lanes(parts, l_pad)
+            r_impl = self.bucket_impl(bucket, impl)
+            # every argument is rebuilt per step (host-assembled stacks,
+            # fresh draws), so the donating twin is alias-safe here too
+            launch = self._launch("fused_ehvi",
+                                  fused_ehvi_launch_fn(donate=False),
+                                  fused_ehvi_launch_fn(donate=True))
+        with span("launch", kind="ehvi"):
+            out = launch(*parts, impl=r_impl)
+        with span("unpack", kind="ehvi"):
+            return [np.asarray(out[j])[:q] for j in range(len(queries))]
 
     def _exec_fit(self, bucket, queries, plan, impl):
         """One ``kernels.fused_fit`` launch for the bucket: pack the raw
@@ -1133,40 +1157,43 @@ class PlanExecutor:
         from repro.kernels.fused_fit import fused_fit_launch_fn
         d, steps, noise = bucket.key
         n_pad, m_pad = bucket.pads["n_pad"], bucket.pads["m_pad"]
-        xs = [np.asarray(query.x, np.float32) for query in queries]
-        ys = [np.asarray(query.y, np.float32) for query in queries]
-        ns = [int(yi.shape[0]) for yi in ys]
-        if m_pad > len(queries):   # padded lanes repeat lane 0, thrown away
-            extra = m_pad - len(queries)
-            xs += [xs[0]] * extra
-            ys += [ys[0]] * extra
-            ns += [ns[0]] * extra
-        x_np, ysd, mask_np, y_mean, y_std = _pack_fit_lanes(
-            xs, ys, ns, n_pad)
-        ils = np.zeros((m_pad, d), np.float32)
-        isf = np.zeros((m_pad,), np.float32)
-        for j, query in enumerate(queries):
-            if query.init_ls is not None:
-                ils[j] = np.asarray(query.init_ls, np.float32)
-                isf[j] = np.float32(query.init_sf)
-        gx = jnp.asarray(x_np)
-        gy = jnp.asarray(ysd)
-        gmask = jnp.asarray(mask_np)
-        # all five launch args are host-built fresh above (device
-        # transfers of new numpy buffers), so donation is alias-safe
-        # without the single-query guard; only gils/gisf (the donated
-        # positions) die at launch — x/y/mask stay live to seed the
-        # returned BatchedGP
-        gils = jnp.asarray(ils)
-        gisf = jnp.asarray(isf)
-        r_impl = self.bucket_impl(bucket, impl)
-        launch = self._launch("fused_fit",
-                              fused_fit_launch_fn(donate=False),
-                              fused_fit_launch_fn(donate=True))
-        log_ls, log_sf, chol, alpha = launch(
-            gx, gy, gmask, gils, gisf, steps=steps, noise=noise,
-            impl=r_impl)
-        stack = BatchedGP(gx, gy, gmask, jnp.asarray(y_mean),
-                          jnp.asarray(y_std), log_ls, log_sf, noise,
-                          chol, alpha, jnp.asarray(ns, jnp.int32))
-        return [(stack, j) for j in range(len(queries))]
+        with span("pack", kind="fit"):
+            xs = [np.asarray(query.x, np.float32) for query in queries]
+            ys = [np.asarray(query.y, np.float32) for query in queries]
+            ns = [int(yi.shape[0]) for yi in ys]
+            if m_pad > len(queries):   # padded lanes repeat lane 0
+                extra = m_pad - len(queries)
+                xs += [xs[0]] * extra
+                ys += [ys[0]] * extra
+                ns += [ns[0]] * extra
+            x_np, ysd, mask_np, y_mean, y_std = _pack_fit_lanes(
+                xs, ys, ns, n_pad)
+            ils = np.zeros((m_pad, d), np.float32)
+            isf = np.zeros((m_pad,), np.float32)
+            for j, query in enumerate(queries):
+                if query.init_ls is not None:
+                    ils[j] = np.asarray(query.init_ls, np.float32)
+                    isf[j] = np.float32(query.init_sf)
+            gx = jnp.asarray(x_np)
+            gy = jnp.asarray(ysd)
+            gmask = jnp.asarray(mask_np)
+            # all five launch args are host-built fresh above (device
+            # transfers of new numpy buffers), so donation is alias-safe
+            # without the single-query guard; only gils/gisf (the donated
+            # positions) die at launch — x/y/mask stay live to seed the
+            # returned BatchedGP
+            gils = jnp.asarray(ils)
+            gisf = jnp.asarray(isf)
+            r_impl = self.bucket_impl(bucket, impl)
+            launch = self._launch("fused_fit",
+                                  fused_fit_launch_fn(donate=False),
+                                  fused_fit_launch_fn(donate=True))
+        with span("launch", kind="fit"):
+            log_ls, log_sf, chol, alpha = launch(
+                gx, gy, gmask, gils, gisf, steps=steps, noise=noise,
+                impl=r_impl)
+        with span("unpack", kind="fit"):
+            stack = BatchedGP(gx, gy, gmask, jnp.asarray(y_mean),
+                              jnp.asarray(y_std), log_ls, log_sf, noise,
+                              chol, alpha, jnp.asarray(ns, jnp.int32))
+            return [(stack, j) for j in range(len(queries))]

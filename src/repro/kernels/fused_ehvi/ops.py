@@ -54,9 +54,7 @@ def _fused_ehvi_launch(los, his, refs, mu, var, y_mean, y_std, eps,
 
 
 _fused_ehvi_launch_donated = jax.jit(
-    lambda los, his, refs, mu, var, y_mean, y_std, eps, impl="xla":
-        fused_ehvi(los, his, refs, mu, var, y_mean, y_std, eps, impl=impl),
-    static_argnames=("impl",),
+    _fused_ehvi_launch.__wrapped__, static_argnames=("impl",),
     donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
 
 

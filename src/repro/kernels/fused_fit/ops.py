@@ -62,10 +62,7 @@ def _fused_fit_launch(x, y, mask, init_ls, init_sf, steps: int = 120,
 
 
 _fused_fit_launch_donated = jax.jit(
-    lambda x, y, mask, init_ls, init_sf, steps=120, noise=0.1, lr=0.05, \
-           impl="xla":
-        fused_fit(x, y, mask, init_ls, init_sf, steps=steps, noise=noise,
-                  lr=lr, impl=impl),
+    _fused_fit_launch.__wrapped__,
     static_argnames=("steps", "noise", "lr", "impl"),
     donate_argnums=(3, 4))
 

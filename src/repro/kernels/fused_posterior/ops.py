@@ -7,14 +7,15 @@ convention: ``"xla"`` is the vmapped reference chain, ``"pallas"`` /
 ``"pallas_interpret"`` the fused kernel, and ``"auto"`` routes through
 ``kernels.routing.resolve_impl`` on the bucket's output cell count.
 
-``_fused_launch`` is the jitted entry the plan executor calls — one
-compile per bucket shape, so it belongs to the precompilable launch
-vocabulary tracked by ``launch.compile_stats``. On TPU the executor
-uses ``_fused_launch_donated`` instead: the stacked observation-cache
-buffers (x, mask, chol, alpha, grid, eps-free lanes) are rebuilt from
-the sessions' stacks every step, so the launch donates them and XLA
-reuses their HBM for the solve intermediates. CPU/GPU skip donation —
-those backends cannot alias them and would warn on every launch.
+``_fused_posterior_launch`` is the jitted entry the plan executor calls
+— one compile per bucket shape, so it belongs to the precompilable
+launch vocabulary tracked by ``launch.compile_stats``. On TPU the
+executor uses ``_fused_posterior_launch_donated`` instead: the stacked
+observation-cache buffers (x, mask, chol, alpha, grid, eps-free lanes)
+are rebuilt from the sessions' stacks every step, so the launch
+donates them and XLA reuses their HBM for the solve intermediates.
+CPU/GPU skip donation — those backends cannot alias them and would warn
+on every launch.
 """
 from __future__ import annotations
 
@@ -45,17 +46,15 @@ def fused_posterior_ei(log_ls, log_sf, x, mask, chol, alpha, xq, best, *,
 
 
 @partial(jax.jit, static_argnames=("impl",))
-def _fused_launch(log_ls, log_sf, x, mask, chol, alpha, xq, best,
-                  impl: str = "xla"):
+def _fused_posterior_launch(log_ls, log_sf, x, mask, chol, alpha, xq,
+                            best, impl: str = "xla"):
     return fused_posterior_ei(log_ls, log_sf, x, mask, chol, alpha, xq,
                               best, impl=impl)
 
 
-_fused_launch_donated = jax.jit(
-    lambda log_ls, log_sf, x, mask, chol, alpha, xq, best, impl="xla":
-        fused_posterior_ei(log_ls, log_sf, x, mask, chol, alpha, xq,
-                           best, impl=impl),
-    static_argnames=("impl",), donate_argnums=(2, 3, 4, 5, 6))
+_fused_posterior_launch_donated = jax.jit(
+    _fused_posterior_launch.__wrapped__, static_argnames=("impl",),
+    donate_argnums=(2, 3, 4, 5, 6))
 
 
 def fused_launch_fn(donate=None):
@@ -66,7 +65,8 @@ def fused_launch_fn(donate=None):
     entry's jit cache gets warmed."""
     if donate is None:
         donate = jax.default_backend() == "tpu"
-    return _fused_launch_donated if donate else _fused_launch
+    return (_fused_posterior_launch_donated if donate
+            else _fused_posterior_launch)
 
 
 def ref_twin():

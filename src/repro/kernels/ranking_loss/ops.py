@@ -103,9 +103,8 @@ def _ranking_loss_launch(preds, ys, n_valid, impl: str = "xla"):
 
 
 _ranking_loss_launch_donated = jax.jit(
-    lambda preds, ys, n_valid, impl="xla":
-        ranking_loss_padded(preds, ys, n_valid, impl=impl),
-    static_argnames=("impl",), donate_argnums=(2,))
+    _ranking_loss_launch.__wrapped__, static_argnames=("impl",),
+    donate_argnums=(2,))
 
 
 def ranking_loss_launch_fn(donate=None):

@@ -2,10 +2,13 @@
 
 The compile-once steady state is a claim about a FINITE set of jitted
 launch functions: the fused fit, the per-kind plan launches (each with
-its buffer-donating twin), and the fused posterior / fused EHVI
-kernels. This module registers exactly that set and counts their
-compiles via jit-cache sizes, so a service can assert "zero recompiles
-after precompile" instead of hoping for it.
+its buffer-donating twin), the fused posterior / fused EHVI kernels,
+and the support-model fit. This module registers exactly that set and
+counts their compiles via jit-cache sizes, so a service can assert
+"zero recompiles after precompile" instead of hoping for it. The
+support fit is unpadded and outside the precompiled vocabulary: it
+compiles once per support history length, and a serving step that
+fits a support model at a new length counts that miss.
 
 Counting by cache-size delta (rather than a global XLA compile hook) is
 deliberate: a step also runs eager ops at genuinely varying shapes —
@@ -65,7 +68,7 @@ _STATIC_NAMES = frozenset({
     "sample_donated", "loo", "loo_donated", "ehvi", "ehvi_donated",
     "fused_posterior", "fused_posterior_donated", "fused_ehvi",
     "fused_ehvi_donated", "fused_fit", "fused_fit_donated",
-    "ranking_loss", "ranking_loss_donated"})
+    "ranking_loss", "ranking_loss_donated", "support_fit"})
 
 
 def register_launch(name: str, fn) -> None:
@@ -107,14 +110,17 @@ def tracked_launches() -> Dict[str, object]:
         "loo_donated": gp._batched_loo_launch_donated,
         "ehvi": acquisition._ehvi_box_launch,
         "ehvi_donated": acquisition._ehvi_box_launch_donated,
-        "fused_posterior": fused_ops._fused_launch,
-        "fused_posterior_donated": fused_ops._fused_launch_donated,
+        "fused_posterior": fused_ops._fused_posterior_launch,
+        "fused_posterior_donated": fused_ops._fused_posterior_launch_donated,
         "fused_ehvi": fused_ehvi_ops._fused_ehvi_launch,
         "fused_ehvi_donated": fused_ehvi_ops._fused_ehvi_launch_donated,
         "fused_fit": fused_fit_ops._fused_fit_launch,
         "fused_fit_donated": fused_fit_ops._fused_fit_launch_donated,
         "ranking_loss": ranking_ops._ranking_loss_launch,
         "ranking_loss_donated": ranking_ops._ranking_loss_launch_donated,
+        # the support-model fit ``SupportModelStore`` reaches through
+        # ``fit_gp``: unpadded, so each new history length compiles
+        "support_fit": gp._fit,
     }
 
 
@@ -153,9 +159,18 @@ class CompileWatcher:
     def __init__(self):
         self._base = cache_sizes()
 
+    def delta(self) -> Dict[str, int]:
+        """name -> programs that tracked launch compiled since the
+        snapshot, for the launches that compiled any."""
+        out = {}
+        for name, size in cache_sizes().items():
+            grew = size - self._base.get(name, 0)
+            if grew > 0:
+                out[name] = grew
+        return out
+
     def misses(self) -> int:
-        return sum(max(0, size - self._base.get(name, 0))
-                   for name, size in cache_sizes().items())
+        return sum(self.delta().values())
 
     def reset(self) -> None:
         self._base = cache_sizes()

@@ -82,6 +82,7 @@ from repro.kernels.ranking_loss import ranking_loss_launch_fn
 from repro.core.types import (BOResult, Constraint, Objective, Observation,
                               RunRecord)
 from repro.launch.compile_stats import CompileWatcher
+from repro.launch.spans import root, span
 from repro.serve.plan import (CohortLimits, EhviQuery, FitQuery,
                               LooSampleQuery, PlanExecutor,
                               PosteriorDrawQuery, PosteriorQuery,
@@ -302,20 +303,27 @@ class SearchService:
     """
 
     # how each plan-node kind rolls up into the service stats (the
-    # sample-side kinds share one triple: they are all "draws the step
+    # sample-side kinds share one pair: they are all "draws the step
     # needed", whether from a support stack, a LOO target, or posterior
-    # rows); the third element accumulates the kind's host-side
-    # dispatch wall from the executor's per-bucket counters
-    _STAT_KEYS = {"posterior": ("posterior_batches", "posterior_queries",
-                                "posterior_wall_s"),
-                  "sample": ("sample_batches", "sample_queries",
-                             "sample_wall_s"),
-                  "loo": ("sample_batches", "sample_queries",
-                          "sample_wall_s"),
-                  "draw": ("sample_batches", "sample_queries",
-                           "sample_wall_s"),
-                  "ehvi": ("ehvi_batches", "ehvi_jobs", "ehvi_wall_s"),
-                  "fit": ("fit_batches", "fit_jobs", "fit_wall_s")}
+    # rows)
+    _STAT_KEYS = {"posterior": ("posterior_batches", "posterior_queries"),
+                  "sample": ("sample_batches", "sample_queries"),
+                  "loo": ("sample_batches", "sample_queries"),
+                  "draw": ("sample_batches", "sample_queries"),
+                  "ehvi": ("ehvi_batches", "ehvi_jobs"),
+                  "fit": ("fit_batches", "fit_jobs")}
+
+    # the phase spans of ``step`` (children of ``karasu.step``), in order:
+    # admit, absorb (poll/drain), profile_wait (the blocking collect when
+    # every session waits), fit.collect (FitQuery building), fit.cache
+    # (the warm-start refresh, a host transfer), regroup (target stacks
+    # and their posterior queries), select (candidate-index queries,
+    # support stacks, target extracts), score (RGPE weights, its sample
+    # round nested), moo.front (fronts and EHVI queries), acquire
+    # (per-tenant acquisition and the next runs' submits), finish
+    STEP_PHASES = ("admit", "absorb", "profile_wait", "fit.collect",
+                   "fit.cache", "regroup", "select", "score", "moo.front",
+                   "acquire", "finish")
 
     def __init__(self, repository: Optional[Repository] = None, *,
                  slots: int = 8, executor=None, wait_mode: str = "any",
@@ -367,11 +375,12 @@ class SearchService:
                       "sample_queries": 0, "ehvi_batches": 0,
                       "ehvi_jobs": 0, "plan_batches": 0, "plan_queries": 0,
                       "plan_compile_misses": 0, "precompiled_buckets": 0,
-                      "precompile_compiles": 0, "fit_wall_s": 0.0,
-                      "posterior_wall_s": 0.0, "sample_wall_s": 0.0,
-                      "ehvi_wall_s": 0.0, "plan_wall_s": 0.0,
+                      "precompile_compiles": 0,
                       "fit_warm_lanes": 0, "fit_cold_lanes": 0,
                       "fit_fused_batches": 0}
+        # ``step`` and ``precompile`` also add, as they occur, the spans'
+        # self seconds (``span_s.<phase>``) and the programs each phase
+        # built (``compiles.<phase>``): see ``repro.launch.spans``
         # launch signatures covered by precompile() — empty until called
         self.precompiled_signatures: set = set()
 
@@ -450,7 +459,13 @@ class SearchService:
         ensemble rows — at most ``max_lanes`` stacks of ``n_samples``
         draws — rounded by the lane policy, and its column count rounds
         like an observation axis. Returns ``{"buckets", "compiles"}``
-        and folds both into ``stats``."""
+        and folds both into ``stats``. It is the root span
+        ``karasu.precompile``, so ``stats["compiles.<span>"]`` says
+        which of its phases built the programs."""
+        with root("precompile", self.stats):
+            return self._precompile(limits)
+
+    def _precompile(self, limits: CohortLimits) -> Dict[str, int]:
         watch = CompileWatcher()
         # a step's EHVI launch has one lane per multi-objective session,
         # and at most ``slots`` sessions are active
@@ -594,7 +609,17 @@ class SearchService:
         of sessions whose next profiling run was launched.
         ``profile_timeout`` overrides the service-level default for this
         step's blocking executor waits (used by ``collect(wait=True)``
-        to honor its own deadline)."""
+        to honor its own deadline).
+
+        The step is the root span ``karasu.step`` (``repro.launch.spans``,
+        step number ``stats["steps"]``); its children are the disjoint
+        ``STEP_PHASES`` plus the planner's and executor's spans of the
+        rounds that run directly under it, and together they cover it."""
+        self.stats["steps"] += 1
+        with root("step", self.stats, step_num=self.stats["steps"]):
+            return self._step(profile_timeout)
+
+    def _step(self, profile_timeout: Optional[float]) -> int:
         wait_t = (self.profile_timeout if profile_timeout is None
                   else profile_timeout)
         # one deadline for the WHOLE step: wait_mode="all" may wait twice
@@ -606,35 +631,40 @@ class SearchService:
             return (None if deadline is None
                     else max(0.0, deadline - time.monotonic()))
 
-        self.stats["steps"] += 1
         # any compile of a tracked plan launch during this step is a
         # steady-state violation candidate — surfaced, never silent
         compile_watch = CompileWatcher()
-        self._admit()
-        self._absorb(self.executor.poll())
-        if self.wait_mode == "all" and self.executor.pending():
-            self._absorb(self.executor.drain(left()))
+        with span("admit"):
+            self._admit()
+        with span("absorb"):
+            self._absorb(self.executor.poll())
+            if self.wait_mode == "all" and self.executor.pending():
+                self._absorb(self.executor.drain(left()))
+            ready = self._ready_sessions()
 
-        ready = self._ready_sessions()
         if not ready and self.executor.pending():
             # every active session is WAITING_PROFILE: block until at
             # least one result lands rather than spinning
-            self.stats["profile_waits"] += 1
-            self._absorb(self.executor.collect(left()))
-            ready = self._ready_sessions()
+            with span("profile_wait"):
+                self.stats["profile_waits"] += 1
+                self._absorb(self.executor.collect(left()))
+                ready = self._ready_sessions()
 
-        # a session whose completed runs ALL errored has nothing to fit:
-        # re-admit it with a fresh random candidate instead of scoring
-        # (failed candidates stay reserved in `profiled`, never retried)
-        for s, rem in ready:
-            if not s.observations:
-                ci = rem[int(s.rng.integers(len(rem)))]
-                self.executor.submit(s.launch(ci, "init"),
-                                     s.req.profile_fn)
-        ready = [(s, rem) for s, rem in ready if s.observations]
+        with span("admit"):
+            # a session whose completed runs ALL errored has nothing to
+            # fit: re-admit it with a fresh random candidate instead of
+            # scoring (failed candidates stay reserved in `profiled`,
+            # never retried)
+            for s, rem in ready:
+                if not s.observations:
+                    ci = rem[int(s.rng.integers(len(rem)))]
+                    self.executor.submit(s.launch(ci, "init"),
+                                         s.req.profile_fn)
+            ready = [(s, rem) for s, rem in ready if s.observations]
         if not ready:
-            self._absorb(self.executor.poll())
-            self.stats["plan_compile_misses"] += compile_watch.misses()
+            with span("absorb"):
+                self._absorb(self.executor.poll())
+                self.stats["plan_compile_misses"] += compile_watch.misses()
             return 0
 
         # the model math of the step: two planned rounds over the query
@@ -644,35 +674,39 @@ class SearchService:
         moo_acq = self._moo_phase(
             [(s, rem) for s, rem in ready if s.is_moo], posts)
 
-        advanced = 0
-        for s, rem in ready:
-            if s.is_moo:
-                # MC-EHVI x PoF; no scalar incumbent, so no early stop
-                acq = moo_acq[s.rid]
-            else:
-                acq, best_raw, obj_post = _acquisition(
-                    posts[s.rid], s.observations, s.req.objective,
-                    s.req.constraints)
-                acq = acq[np.asarray(rem)]
+        with span("acquire"):
+            advanced = 0
+            for s, rem in ready:
+                if s.is_moo:
+                    # MC-EHVI x PoF; no scalar incumbent, so no early stop
+                    acq = moo_acq[s.rid]
+                else:
+                    acq, best_raw, obj_post = _acquisition(
+                        posts[s.rid], s.observations, s.req.objective,
+                        s.req.constraints)
+                    acq = acq[np.asarray(rem)]
 
-                if _should_stop_early(s.cfg, len(s.observations), acq,
-                                      obj_post, best_raw):
-                    s.stopped_at = len(s.observations)
-                    self._finish(s)
-                    continue
+                    if _should_stop_early(s.cfg, len(s.observations), acq,
+                                          obj_post, best_raw):
+                        s.stopped_at = len(s.observations)
+                        self._finish(s)
+                        continue
 
-            self.executor.submit(s.launch(rem[int(np.argmax(acq))]),
-                                 s.req.profile_fn)
-            advanced += 1
-            self.stats["iterations"] += 1
+                self.executor.submit(s.launch(rem[int(np.argmax(acq))]),
+                                     s.req.profile_fn)
+                advanced += 1
+                self.stats["iterations"] += 1
 
         # with a synchronous executor every launch has already landed;
         # absorbing here preserves the one-step-one-iteration semantics
-        self._absorb(self.executor.poll())
-        for s in list(self.active.values()):
-            if s.state == READY and len(s.observations) >= s.cfg.max_iters:
-                self._finish(s)
-        self.stats["plan_compile_misses"] += compile_watch.misses()
+        with span("absorb"):
+            self._absorb(self.executor.poll())
+        with span("finish"):
+            for s in list(self.active.values()):
+                if (s.state == READY
+                        and len(s.observations) >= s.cfg.max_iters):
+                    self._finish(s)
+            self.stats["plan_compile_misses"] += compile_watch.misses()
         return advanced
 
     def _ready_sessions(self) -> List[Tuple[_Session, List[int]]]:
@@ -695,16 +729,14 @@ class SearchService:
 
     def _count_plan(self, counters: Dict[str, Dict[str, int]]) -> None:
         """Roll one planned round's per-kind counters into the service
-        stats: the per-kind triples (``_STAT_KEYS``) plus the aggregate
-        ``plan_batches``/``plan_queries``/``plan_wall_s``."""
+        stats: the per-kind pairs (``_STAT_KEYS``) plus the aggregate
+        ``plan_batches``/``plan_queries``."""
         for kind, c in counters.items():
-            bk, qk, wk = self._STAT_KEYS[kind]
+            bk, qk = self._STAT_KEYS[kind]
             self.stats[bk] += c.get("launches", 0)
             self.stats[qk] += c.get("queries", 0)
-            self.stats[wk] += c.get("wall_s", 0.0)
             self.stats["plan_batches"] += c.get("launches", 0)
             self.stats["plan_queries"] += c.get("queries", 0)
-            self.stats["plan_wall_s"] += c.get("wall_s", 0.0)
 
     @staticmethod
     def _regroup_fit(entries: List[Tuple[BatchedGP, int]],
@@ -767,14 +799,6 @@ class SearchService:
         still plans)."""
         groups: Dict[Tuple[Any, float], List[_Session]] = {}
         posts: Dict[int, Dict[str, Dict]] = {}
-        for s in sessions:
-            if s.req.method == "augmented":
-                # Extra-Trees have no batched path; keep them per-session
-                posts[s.rid] = _model_posteriors_augmented(
-                    s.observations, s.measures, s.cfg, s.xq_all, s.req.seed)
-                continue
-            groups.setdefault((s.space_key, s.cfg.noise), []).append(s)
-
         # -- collect: the fit round ------------------------------------------
         # one FitQuery per (session, measure) model across ALL groups —
         # warm lanes (cached hyperparameters) ask for the short refine
@@ -786,25 +810,37 @@ class SearchService:
         fit_queries: List[FitQuery] = []
         fit_owners: List[Tuple[_Session, str]] = []
         group_lanes: Dict[Tuple[Any, float], List[int]] = {}
-        for gk, group in groups.items():
-            noise = gk[1]
-            lanes = group_lanes.setdefault(gk, [])
-            for s in group:
-                x = np.stack([o.x for o in s.observations])
-                for m in s.measures:
-                    y = np.array([o.measures[m] for o in s.observations])
-                    entry = (s.fit_cache.get(m) if self.fit_warm_steps
-                             else None)
-                    if entry is not None:
-                        self.stats["fit_warm_lanes"] += 1
-                        q = FitQuery(x, y, noise, self.fit_warm_steps,
-                                     init_ls=entry[1], init_sf=entry[2])
-                    else:
-                        self.stats["fit_cold_lanes"] += 1
-                        q = FitQuery(x, y, noise, self.fit_steps)
-                    lanes.append(len(fit_queries))
-                    fit_queries.append(q)
-                    fit_owners.append((s, m))
+        with span("fit.collect"):
+            for s in sessions:
+                if s.req.method == "augmented":
+                    # Extra-Trees have no batched path; keep them
+                    # per-session
+                    posts[s.rid] = _model_posteriors_augmented(
+                        s.observations, s.measures, s.cfg, s.xq_all,
+                        s.req.seed)
+                    continue
+                groups.setdefault((s.space_key, s.cfg.noise),
+                                  []).append(s)
+            for gk, group in groups.items():
+                noise = gk[1]
+                lanes = group_lanes.setdefault(gk, [])
+                for s in group:
+                    x = np.stack([o.x for o in s.observations])
+                    for m in s.measures:
+                        y = np.array([o.measures[m]
+                                      for o in s.observations])
+                        entry = (s.fit_cache.get(m) if self.fit_warm_steps
+                                 else None)
+                        if entry is not None:
+                            self.stats["fit_warm_lanes"] += 1
+                            q = FitQuery(x, y, noise, self.fit_warm_steps,
+                                         init_ls=entry[1], init_sf=entry[2])
+                        else:
+                            self.stats["fit_cold_lanes"] += 1
+                            q = FitQuery(x, y, noise, self.fit_steps)
+                        lanes.append(len(fit_queries))
+                        fit_queries.append(q)
+                        fit_owners.append((s, m))
         fc: Dict[str, Dict[str, int]] = {}
         fit_res = self.plan_executor.execute(
             self.planner.plan(fit_queries), counters=fc)
@@ -813,54 +849,57 @@ class SearchService:
             fc.get("fit", {}).get("launches", 0)
         # refresh every lane's warm-start cache from the fitted stacks
         # (one host transfer per bucket stack, not per lane)
-        host: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-        for (s, m), (st, ln) in zip(fit_owners, fit_res):
-            h = host.get(id(st))
-            if h is None:
-                h = (np.asarray(st.log_lengthscales),
-                     np.asarray(st.log_signal))
-                host[id(st)] = h
-            s.fit_cache[m] = (len(s.observations), h[0][ln], h[1][ln])
+        with span("fit.cache"):
+            host: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
+            for (s, m), (st, ln) in zip(fit_owners, fit_res):
+                h = host.get(id(st))
+                if h is None:
+                    h = (np.asarray(st.log_lengthscales),
+                         np.asarray(st.log_signal))
+                    host[id(st)] = h
+                s.fit_cache[m] = (len(s.observations), h[0][ln], h[1][ln])
 
         # -- collect: posteriors over the fitted stacks ----------------------
-        # (session, measure, bases, WeightJob) across ALL groups
-        rgpe_jobs: List[Tuple[_Session, str, Any, WeightJob]] = []
         queries: List[PosteriorQuery] = []
-        for gk, group in groups.items():
-            noise = gk[1]
-            owners = [(s, m) for s in group for m in s.measures]
-            tgts = self._regroup_fit(
-                [fit_res[i] for i in group_lanes[gk]], noise)
+        with span("regroup"):
+            tgts = {gk: self._regroup_fit(
+                [fit_res[i] for i in group_lanes[gk]], gk[1])
+                for gk in groups}
+            owners = {gk: [(s, m) for s in group for m in s.measures]
+                      for gk, group in groups.items()}
+            for gk, group in groups.items():
+                xq_all = group[0].xq_all
+                if self.fuse_posteriors:
+                    queries.append(PosteriorQuery(
+                        tgts[gk], xq_all,
+                        owner=lambda res, o=owners[gk], t=tgts[gk]:
+                            _absorb_target_posts(posts, o, t, *res)))
+                else:
+                    mu_all, var_all = batched_posterior(tgts[gk], xq_all)
+                    _absorb_target_posts(posts, owners[gk], tgts[gk],
+                                         mu_all, var_all)
 
-            xq_all = group[0].xq_all
-            if self.fuse_posteriors:
-                queries.append(PosteriorQuery(
-                    tgts, xq_all,
-                    owner=lambda res, o=owners, t=tgts:
-                        _absorb_target_posts(posts, o, t, *res)))
-            else:
-                mu_all, var_all = batched_posterior(tgts, xq_all)
-                _absorb_target_posts(posts, owners, tgts, mu_all, var_all)
+        # (session, measure, bases, WeightJob) across ALL groups
+        with span("select"):
+            rgpe_jobs: List[Tuple[_Session, str, Any, WeightJob]] = [
+                job for gk, group in groups.items() for s in group
+                if s.req.method == "karasu"
+                for job in self._rgpe_jobs(s, tgts[gk], owners[gk])]
 
-            for s in group:
-                if s.req.method == "karasu":
-                    rgpe_jobs.extend(self._rgpe_jobs(s, tgts, owners))
-
-        weights = self._score_weights(rgpe_jobs)
-
-        if not self.fuse_posteriors:
+        with span("score"):
+            weights = self._score_weights(rgpe_jobs)
+            if not self.fuse_posteriors:
+                for i, (s, m, bases, _job) in enumerate(rgpe_jobs):
+                    self._mix_rgpe(s, m, bases, weights[i], posts[s.rid])
+                return posts
+            # support stacks join the targets' queries; the executor
+            # fires owners in query order, so mixes overlay the target
+            # rows the earlier queries already absorbed into ``posts``
             for i, (s, m, bases, _job) in enumerate(rgpe_jobs):
-                self._mix_rgpe(s, m, bases, weights[i], posts[s.rid])
-            return posts
-
-        # support stacks join the targets' queries; the executor fires
-        # owners in query order, so mixes overlay the target rows the
-        # earlier queries already absorbed into ``posts``
-        for i, (s, m, bases, _job) in enumerate(rgpe_jobs):
-            queries.append(PosteriorQuery(
-                bases, s.xq_all,
-                owner=lambda res, s=s, m=m, w=weights[i]:
-                    self._mix_into(posts, s, m, w, res)))
+                queries.append(PosteriorQuery(
+                    bases, s.xq_all,
+                    owner=lambda res, s=s, m=m, w=weights[i]:
+                        self._mix_into(posts, s, m, w, res)))
         if not queries:
             return posts
 
@@ -889,10 +928,8 @@ class SearchService:
             self.stats["rgpe_jobs"] += len(idxs)
             self.stats["sample_batches"] += sc.get("launches", 0)
             self.stats["sample_queries"] += sc.get("queries", 0)
-            self.stats["sample_wall_s"] += sc.get("wall_s", 0.0)
             self.stats["plan_batches"] += sc.get("launches", 0)
             self.stats["plan_queries"] += sc.get("queries", 0)
-            self.stats["plan_wall_s"] += sc.get("wall_s", 0.0)
             for i, w in zip(idxs, ws):
                 weights[i] = w
         return weights
@@ -1000,17 +1037,18 @@ class SearchService:
         samples: Dict[int, List[Optional[np.ndarray]]] = {
             s.rid: [None] * len(s.objectives) for s, _ in moo_ready}
         draw_queries: List[PosteriorDrawQuery] = []
-        for s, rem in moo_ready:
-            idx = np.asarray(rem)
-            it = len(s.observations)
-            for oi, obj in enumerate(s.objectives):
-                p = posts[s.rid][obj.name]
-                k = derive_key(s.key, KEY_PURPOSE_MOO_EHVI, it, oi)
-                draw_queries.append(PosteriorDrawQuery(
-                    p["mu"][idx], p["var"][idx], p["y_mean"], p["y_std"],
-                    k, s.req.n_mc,
-                    owner=lambda d, rid=s.rid, oi=oi:
-                        samples[rid].__setitem__(oi, np.asarray(d))))
+        with span("moo.front"):
+            for s, rem in moo_ready:
+                idx = np.asarray(rem)
+                it = len(s.observations)
+                for oi, obj in enumerate(s.objectives):
+                    p = posts[s.rid][obj.name]
+                    k = derive_key(s.key, KEY_PURPOSE_MOO_EHVI, it, oi)
+                    draw_queries.append(PosteriorDrawQuery(
+                        p["mu"][idx], p["var"][idx], p["y_mean"],
+                        p["y_std"], k, s.req.n_mc,
+                        owner=lambda d, rid=s.rid, oi=oi:
+                            samples[rid].__setitem__(oi, np.asarray(d))))
         dc: Dict[str, Dict[str, int]] = {}
         self.plan_executor.execute(self.planner.plan(draw_queries),
                                    counters=dc)
@@ -1019,13 +1057,14 @@ class SearchService:
         # -- collect / plan / execute / scatter: the EHVI round --------------
         out: Dict[int, np.ndarray] = {}
         ehvi_queries = []
-        for s, rem in moo_ready:
-            observed, ref = self._moo_front_ref(s)
-            ehvi_queries.append(EhviQuery(
-                tuple(samples[s.rid]), observed, ref,
-                owner=lambda acq, s=s, rem=rem:
-                    out.__setitem__(s.rid, self._apply_pof(
-                        s, posts[s.rid], np.asarray(rem), acq))))
+        with span("moo.front"):
+            for s, rem in moo_ready:
+                observed, ref = self._moo_front_ref(s)
+                ehvi_queries.append(EhviQuery(
+                    tuple(samples[s.rid]), observed, ref,
+                    owner=lambda acq, s=s, rem=rem:
+                        out.__setitem__(s.rid, self._apply_pof(
+                            s, posts[s.rid], np.asarray(rem), acq))))
         ec: Dict[str, Dict[str, int]] = {}
         self.plan_executor.execute(self.planner.plan(ehvi_queries),
                                    counters=ec)
@@ -1044,24 +1083,25 @@ class SearchService:
         never changes a session's draws or its acquisition."""
         out: Dict[int, np.ndarray] = {}
         ehvi_queries = []
-        for s, rem in moo_ready:
-            idx = np.asarray(rem)
-            it = len(s.observations)
-            observed, ref = self._moo_front_ref(s)
-            ps = [posts[s.rid][obj.name] for obj in s.objectives]
-            ehvi_queries.append(EhviQuery(
-                None, observed, ref,
-                mu=tuple(p["mu"][idx] for p in ps),
-                var=tuple(p["var"][idx] for p in ps),
-                y_mean=tuple(float(p["y_mean"]) for p in ps),
-                y_std=tuple(float(p["y_std"]) for p in ps),
-                keys=tuple(
-                    derive_key(s.key, KEY_PURPOSE_MOO_EHVI, it, oi)
-                    for oi in range(len(s.objectives))),
-                n_mc=s.req.n_mc,
-                owner=lambda acq, s=s, rem=rem:
-                    out.__setitem__(s.rid, self._apply_pof(
-                        s, posts[s.rid], np.asarray(rem), acq))))
+        with span("moo.front"):
+            for s, rem in moo_ready:
+                idx = np.asarray(rem)
+                it = len(s.observations)
+                observed, ref = self._moo_front_ref(s)
+                ps = [posts[s.rid][obj.name] for obj in s.objectives]
+                ehvi_queries.append(EhviQuery(
+                    None, observed, ref,
+                    mu=tuple(p["mu"][idx] for p in ps),
+                    var=tuple(p["var"][idx] for p in ps),
+                    y_mean=tuple(float(p["y_mean"]) for p in ps),
+                    y_std=tuple(float(p["y_std"]) for p in ps),
+                    keys=tuple(
+                        derive_key(s.key, KEY_PURPOSE_MOO_EHVI, it, oi)
+                        for oi in range(len(s.objectives))),
+                    n_mc=s.req.n_mc,
+                    owner=lambda acq, s=s, rem=rem:
+                        out.__setitem__(s.rid, self._apply_pof(
+                            s, posts[s.rid], np.asarray(rem), acq))))
         ec: Dict[str, Dict[str, int]] = {}
         self.plan_executor.execute(self.planner.plan(ehvi_queries),
                                    counters=ec)

@@ -116,3 +116,15 @@ def test_compile_cache_dir_env_wins_else_checkout(monkeypatch, tmp_path):
     finally:
         for k, v in saved.items():
             jax.config.update(k, v)
+
+
+def test_tracked_launches_are_named_for_their_leg():
+    """No tracked launch wraps a lambda (its XLA module would be the
+    anonymous ``jit__lambda_``), and each launch of the static
+    vocabulary is a function whose name contains its leg, so a device
+    trace tells the legs' modules apart."""
+    for name, fn in compile_stats.tracked_launches().items():
+        assert fn.__name__ != "<lambda>", name
+        if name in compile_stats._STATIC_NAMES:
+            leg = name.removesuffix("_donated").removeprefix("support_")
+            assert leg in fn.__name__, (name, fn.__name__)

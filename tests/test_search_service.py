@@ -8,6 +8,7 @@ import pytest
 
 from repro.core import (BOConfig, Constraint, Objective, Repository,
                         run_search, run_search_moo, scout_search_space)
+from repro.launch.compile_stats import CompileWatcher
 from repro.serve.profile_executor import (FakeProfileExecutor,
                                           ProcessPoolProfileExecutor,
                                           ProfileJob, SyncProfileExecutor,
@@ -732,8 +733,9 @@ def test_run_search_moo_routes_through_service():
 def test_precompile_zero_recompile_under_mixed_tenant_churn():
     """200 scheduling steps of a churning SO + 2-objective +
     3-objective cohort after an AOT bucket precompile: every planned
-    launch signature lands in the precompiled vocabulary and no
-    tracked launch recompiles (``plan_compile_misses`` stays 0)."""
+    launch signature lands in the precompiled vocabulary and no plan
+    launch recompiles (``plan_compile_misses`` counts only the support
+    fits that the published runs force)."""
     import dataclasses
 
     from repro.core.plan import CohortLimits, StepPlanner
@@ -795,6 +797,7 @@ def test_precompile_zero_recompile_under_mixed_tenant_churn():
                             Objective("runtime")], n_mc=8))
 
     submitted = 0
+    watch = CompileWatcher()
     for _ in range(200):
         while len(svc.active) + len(svc.queue) < 3:
             submit(submitted)
@@ -807,8 +810,13 @@ def test_precompile_zero_recompile_under_mixed_tenant_churn():
     assert {"posterior", "sample", "loo", "ehvi", "fit"} <= \
         {sig[0] for sig in planner.signatures}
     assert planner.signatures <= svc.precompiled_signatures
-    # ...and no tracked launch compiled while serving
-    assert svc.stats["plan_compile_misses"] == 0
+    # ...and no plan launch compiled while serving: the misses are the
+    # support fits alone (tenant-0 publishes, so support histories
+    # grow; the unpadded support fit is outside the vocabulary)
+    compiled = watch.delta()
+    support = compiled.pop("support_fit", 0)
+    assert compiled == {}
+    assert svc.stats["plan_compile_misses"] == support
 
 
 def test_fused_ehvi_service_matches_default_executor():
@@ -846,7 +854,7 @@ def test_precompile_zero_recompile_fused_donated_executor():
     precompile walks the same donate/fused launch choices the serving
     path makes (the donated twins are pinned at executor construction,
     not resolved per call), so a mixed SO + MOO cohort still hits only
-    precompiled signatures with zero tracked recompiles."""
+    precompiled signatures and no plan launch recompiles."""
     import dataclasses
 
     from repro.core.plan import CohortLimits, PlanExecutor, StepPlanner
@@ -905,6 +913,7 @@ def test_precompile_zero_recompile_fused_donated_executor():
                             Objective("runtime")], n_mc=8))
 
     submitted = 0
+    watch = CompileWatcher()
     for _ in range(120):
         while len(svc.active) + len(svc.queue) < 3:
             submit(submitted)
@@ -912,7 +921,12 @@ def test_precompile_zero_recompile_fused_donated_executor():
         svc.step()
     assert len(svc.done) >= 6
     assert planner.signatures <= svc.precompiled_signatures
-    assert svc.stats["plan_compile_misses"] == 0
+    # no plan launch compiled; a support fit at a history length this
+    # process has not fitted before is the only miss there can be
+    compiled = watch.delta()
+    support = compiled.pop("support_fit", 0)
+    assert compiled == {}
+    assert svc.stats["plan_compile_misses"] == support
 
 
 def test_fit_leg_warm_and_cold_rungs_zero_recompile():
@@ -991,3 +1005,33 @@ def test_fit_warm_steps_disabled_runs_every_lane_cold():
     svc.run()
     assert svc.stats["fit_cold_lanes"] > 0
     assert svc.stats["fit_warm_lanes"] == 0
+
+
+def test_support_fit_at_a_new_history_length_counts_a_miss():
+    """``plan_compile_misses`` counts support-model fits: once a
+    collaborator's workload grows to a history length no support fit
+    has had, the next step refits it, compiling ``support_fit`` — and
+    nothing else — and the step counts that miss."""
+    repo = _support_repo(runs=12)
+    svc = SearchService(repo, slots=1)
+    # a noise level no other test fits at: the support fit's compiles
+    # here cannot have been built before
+    svc.submit(SearchRequest(
+        SPACE, lambda c: EMU.run(WID, c, rng=None), Objective("cost"),
+        [Constraint("runtime", RT)], method="karasu",
+        bo_config=BOConfig(max_iters=8, noise=0.1093), seed=4))
+    svc.step()
+    svc.step()                 # both fit rungs built
+    rng = np.random.default_rng(5)
+    have = {tuple(sorted(r.config.items()))
+            for r in repo.runs("anon-0")}
+    fresh = [ci for ci in range(len(SPACE))
+             if tuple(sorted(SPACE.configs[ci].items())) not in have]
+    for ci in fresh[:3]:       # 12 -> 15 runs: the same stack padding
+        repo.add_run(EMU.make_record("anon-0", WID, SPACE.configs[ci],
+                                     rng))
+    misses = svc.stats["plan_compile_misses"]
+    watch = CompileWatcher()
+    svc.step()
+    assert watch.delta() == {"support_fit": 1}
+    assert svc.stats["plan_compile_misses"] - misses == 1
