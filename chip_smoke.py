@@ -186,9 +186,9 @@ class RecordingService(SearchService):
         self.posts = super()._posterior_phase(sessions)
         return self.posts
 
-    def _rgpe_jobs(self, s, tgts, owners):
-        jobs = super()._rgpe_jobs(s, tgts, owners)
-        for _s, m, bases, _job in jobs:
+    def _rgpe_jobs(self, groups, tgts, owners):
+        jobs = super()._rgpe_jobs(groups, tgts, owners)
+        for s, m, bases, _job in jobs:
             self.bases[(s.rid, m)] = bases
         return jobs
 

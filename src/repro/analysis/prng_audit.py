@@ -8,10 +8,10 @@ classes are audited:
 1. **Structural** (AST, over ``core/bo.py`` + the service): a
    ``fold_in`` tag built from ARITHMETIC (``purpose * K + it``) can
    collide for in-range values — every fold tag must be a plain
-   name/constant, every ``derive_key`` call site must pass a
-   ``KEY_PURPOSE_*`` constant, and the declared purpose registry
-   (``bo.KEY_PURPOSES``, mirrored by the service's ``KEY_SCHEDULE``)
-   must be distinct and complete.
+   name/constant, every ``derive_key`` (and batched ``derive_keys``)
+   call site must pass a ``KEY_PURPOSE_*`` constant, and the declared
+   purpose registry (``bo.KEY_PURPOSES``, mirrored by the service's
+   ``KEY_SCHEDULE``) must be distinct and complete.
 
 2. **Behavioural** (concrete enumeration): ``derive_key`` evaluated
    over the full purpose set x iterations x indices must produce
@@ -65,7 +65,8 @@ def check_fold_in_tags(source: Optional[str] = None,
                             "— flattened encodings alias distinct "
                             "(purpose, iteration, index) paths; fold "
                             "each component separately"))
-            if fname == "derive_key" and len(node.args) >= 2:
+            if (fname in ("derive_key", "derive_keys")
+                    and len(node.args) >= 2):
                 purpose = node.args[1]
                 named = (isinstance(purpose, ast.Name)
                          and purpose.id.startswith("KEY_PURPOSE_"))

@@ -14,6 +14,7 @@ when max EI <= 10% of the incumbent and >= 6 runs done).
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Callable, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -25,7 +26,7 @@ from .acquisition import (constrained_ei, expected_improvement, feasible,
 from .encoding import SearchSpace
 from .extra_trees import fit_extra_trees
 from .gp import batched_posterior
-from .plan import PlanExecutor, PosteriorQuery, StepPlanner
+from .plan import PlanExecutor, PosteriorQuery, StepPlanner, round_rows
 from .repository import Repository, SupportModelStore
 from .rgpe import WeightJob, compute_weights_multi, mix_weighted
 from .selection import CandidateIndex
@@ -66,6 +67,31 @@ def derive_key(base: jax.Array, purpose: int, it: int,
     k = jax.random.fold_in(base, purpose)
     k = jax.random.fold_in(k, it)
     return jax.random.fold_in(k, index)
+
+
+@partial(jax.jit, static_argnames=("purpose",))
+def _derive_keys_launch(bases, its, indices, purpose: int):
+    return jax.vmap(lambda b, it, index: derive_key(b, purpose, it, index))(
+        bases, its, indices)
+
+
+def derive_keys(bases: np.ndarray, purpose: int, its: Sequence[int],
+                indices: Sequence[int]) -> np.ndarray:
+    """Row j is ``derive_key(bases[j], purpose, its[j], indices[j])``,
+    bit for bit, as host key data: one jitted launch for all rows, read
+    back once. ``bases`` stacks raw key data (``(J, 2)`` uint32 for the
+    default PRNG); the row axis pads to ``plan.round_rows`` so the
+    launch compiles once per rung."""
+    j = len(its)
+    pad = round_rows(j)
+    b = np.zeros((pad,) + np.shape(bases)[1:], np.uint32)
+    b[:j] = bases
+    it = np.zeros((pad,), np.int32)
+    it[:j] = its
+    ix = np.zeros((pad,), np.int32)
+    ix[:j] = indices
+    return jax.device_get(
+        _derive_keys_launch(b, it, ix, purpose=purpose))[:j]
 
 
 @dataclasses.dataclass(frozen=True)

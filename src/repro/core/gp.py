@@ -73,6 +73,16 @@ class GP:
     def n(self) -> int:
         return int(self.x.shape[0])
 
+    def to_host(self) -> "GP":
+        """The same model with every array read back to host numpy in
+        one ``jax.device_get``."""
+        (x, y_raw, y, y_mean, y_std, ls, sf, chol, alpha) = jax.device_get(
+            (self.x, self.y_raw, self.y, self.y_mean, self.y_std,
+             self.params.log_lengthscales, self.params.log_signal,
+             self.chol, self.alpha))
+        return GP(x, y_raw, y, y_mean, y_std,
+                  GPParams(ls, sf, self.params.noise), chol, alpha)
+
 
 def _kernel(params: GPParams, a: jnp.ndarray, b: jnp.ndarray,
             impl: str = "xla") -> jnp.ndarray:
@@ -215,6 +225,14 @@ class BatchedGP:
     @property
     def n_max(self) -> int:
         return int(self.x.shape[1])
+
+    def to_host(self) -> "BatchedGP":
+        """The same stack with every array read back to host numpy in
+        one ``jax.device_get``: ``extract`` on the copy slices numpy
+        and issues no device op."""
+        return dataclasses.replace(self, **jax.device_get(
+            {f.name: getattr(self, f.name)
+             for f in dataclasses.fields(self) if f.name != "noise"}))
 
     def extract(self, i: int) -> GP:
         """Materialise model i as an unbatched GP (exact un-padding)."""
@@ -419,6 +437,23 @@ def sharded_fit_launches(mesh, axis: str = "data"):
     return pair
 
 
+# the stack fields the plan's launches read (posterior and sample, their
+# fused and sharded twins); the observed targets, their scalers and the
+# counts are only ever read on the host
+LAUNCH_FIELDS = ("x", "mask", "chol", "alpha", "log_lengthscales",
+                 "log_signal")
+
+
+def put_stacks(stacks: Sequence[BatchedGP]) -> List[BatchedGP]:
+    """Host-built stacks (numpy fields) onto the device, all in ONE
+    batched ``jax.device_put`` of the ``LAUNCH_FIELDS``; the other
+    fields stay numpy. Each array put costs the host a fixed overhead,
+    so fields no launch reads are not sent."""
+    arrays = jax.device_put([{f: getattr(st, f) for f in LAUNCH_FIELDS}
+                             for st in stacks])
+    return [dataclasses.replace(st, **a) for st, a in zip(stacks, arrays)]
+
+
 def stack_gps(gps: Sequence[GP], n_max: Optional[int] = None, *,
               round_to: int = 1) -> BatchedGP:
     """Stack already-fitted GPs into a BatchedGP without refitting — the
@@ -426,7 +461,16 @@ def stack_gps(gps: Sequence[GP], n_max: Optional[int] = None, *,
     factor, so posteriors are bit-identical to the unbatched ones.
     ``round_to`` rounds the padded length up to a multiple (same
     jit-shape bucketing as ``fit_gp_batched``), so stacks built at
-    different data sizes land on shared query-plan pad shapes."""
+    different data sizes land on shared query-plan pad shapes. Built on
+    the host and put with ``put_stacks``."""
+    return put_stacks([stack_gps_host(gps, n_max, round_to=round_to)])[0]
+
+
+def stack_gps_host(gps: Sequence[GP], n_max: Optional[int] = None, *,
+                   round_to: int = 1) -> BatchedGP:
+    """``stack_gps``'s padded stack assembled in numpy and left on the
+    host (host-resident models cost no device read), for callers that
+    put many stacks on the device at once (``put_stacks``)."""
     if not gps:
         raise ValueError("stack_gps needs >=1 model")
     d = int(gps[0].x.shape[1])
@@ -460,11 +504,8 @@ def stack_gps(gps: Sequence[GP], n_max: Optional[int] = None, *,
         sf[i] = np.asarray(g.params.log_signal)
         y_mean[i] = float(g.y_mean)
         y_std[i] = float(g.y_std)
-    return BatchedGP(jnp.asarray(x), jnp.asarray(y), jnp.asarray(mask),
-                     jnp.asarray(y_mean), jnp.asarray(y_std),
-                     jnp.asarray(ls), jnp.asarray(sf), noise,
-                     jnp.asarray(chol), jnp.asarray(alpha),
-                     jnp.asarray(ns, jnp.int32))
+    return BatchedGP(x, y, mask, y_mean, y_std, ls, sf, noise, chol, alpha,
+                     np.asarray(ns, np.int32))
 
 
 @partial(jax.jit, static_argnames=("impl",))

@@ -85,6 +85,12 @@ from .gp import (GP, BatchedGP, _batched_loo_launch,
 OBS_ROUND_TO = 8        # observation axis pads to multiples of this
 GRID_ROUND_TO = 8       # sample/EHVI candidate axis pads to multiples
 M_ROUND_POW2 = True     # fused model/lane axis pads to a power of two
+# the select phase's step-wide launches (the Pearson rows of every karasu
+# tenant's target runs, the step's RGPE keys) pad their row axis to a
+# power of two of at least ROW_PAD_MIN; the candidate index pads its runs
+# to multiples of CAND_ROUND_TO, so a few published runs keep its shape
+ROW_PAD_MIN = 8
+CAND_ROUND_TO = 128
 
 
 # ---------------------------------------------------------------------------
@@ -263,6 +269,21 @@ def _round_up(n: int, mult: int) -> int:
 
 def _pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
+
+
+def round_rows(n: int) -> int:
+    """Row pad of a select-phase launch carrying ``n`` live rows."""
+    return max(ROW_PAD_MIN, _pow2(n))
+
+
+def row_pads(max_rows: int) -> List[int]:
+    """Every row pad ``round_rows`` gives for 1..``max_rows`` rows: the
+    closed vocabulary ``SearchService.precompile`` warms."""
+    out, p = [], ROW_PAD_MIN
+    while p <= round_rows(max_rows):
+        out.append(p)
+        p <<= 1
+    return out
 
 
 class StepPlanner:

@@ -139,8 +139,11 @@ class SupportModelStore:
         self._noise = noise
         self._min_runs = min_runs
         self._max_entries = max_entries
-        # (workload, measure) -> (repo version at fit time, GP | None)
-        self._cache: Dict[Tuple[str, str], Tuple[int, Optional[object]]] = {}
+        # (workload, measure) -> (repo version at fit time, GP | None,
+        # its host copy | None): the host copy is read back once, when
+        # the fit is made, so a stack miss builds from host arrays
+        self._cache: Dict[Tuple[str, str],
+                          Tuple[int, Optional[object], Optional[object]]] = {}
         # (workload ids, measure) -> (versions at stack time, stack, ids)
         # in LRU order (most recently used last)
         self._stacked: "OrderedDict[Tuple[Tuple[str, ...], str], " \
@@ -150,8 +153,10 @@ class SupportModelStore:
         self._shared: Dict[Tuple[Tuple[str, ...], str],
                            Tuple[Tuple[int, ...], object,
                                  "SharedStackHandle"]] = {}
+        self._swept_at: Optional[int] = None   # repo version last swept
         self.hits = 0
         self.misses = 0
+        self.stack_misses = 0
         self.evictions = 0
 
     @property
@@ -160,13 +165,18 @@ class SupportModelStore:
 
     def get(self, workload_id: str, measure: str):
         """Support GP for (workload, measure), refit iff data changed."""
+        return self._fitted(workload_id, measure)[1]
+
+    def _fitted(self, workload_id: str, measure: str):
+        """The cache entry of (workload, measure), refit iff data
+        changed: (version, GP | None, host copy | None)."""
         from .gp import fit_gp
         v = self._repo.version(workload_id)
         k = (workload_id, measure)
         hit = self._cache.get(k)
         if hit is not None and hit[0] == v:
             self.hits += 1
-            return hit[1]
+            return hit
         self.misses += 1
         xs, ys = [], []
         for r in self._repo.runs(workload_id):
@@ -175,10 +185,10 @@ class SupportModelStore:
                 ys.append(r.measures[measure])
         if len(ys) >= self._min_runs and np.ptp(ys) > 0:
             gp = fit_gp(np.stack(xs), np.array(ys), noise=self._noise)
+            self._cache[k] = (v, gp, gp.to_host())
         else:
-            gp = None
-        self._cache[k] = (v, gp)
-        return gp
+            self._cache[k] = (v, None, None)
+        return self._cache[k]
 
     def get_stacked(self, workload_ids: Sequence[str], measure: str):
         """BatchedGP over the available support models for ``measure``
@@ -188,40 +198,74 @@ class SupportModelStore:
         step re-requests the same support stacks every round — without
         the cache each request re-assembles and re-uploads the padded
         arrays) and padded to multiples of 8, so the posterior/sample
-        query plans see stable, already-bucketed shapes."""
-        from .gp import stack_gps
+        query plans see stable, already-bucketed shapes. A miss
+        (counted in ``stack_misses``) stacks the models' host copies in
+        numpy and puts the stack on the device in one transfer."""
+        return self.get_stacked_many([(workload_ids, measure)])[0]
+
+    def get_stacked_many(self, requests: Sequence[Tuple[Sequence[str], str]]
+                         ) -> List[Tuple[Optional[object], List[str]]]:
+        """``get_stacked`` for many ``(workload ids, measure)`` requests,
+        in order: every miss's stack is assembled in numpy and all of
+        them go to the device in ONE batched transfer; a key asked for
+        twice is built once."""
+        from .gp import put_stacks, stack_gps_host
         from .plan import OBS_ROUND_TO
-        key = (tuple(workload_ids), measure)
-        vers = tuple(self._repo.version(z) for z in workload_ids)
-        hit = self._stacked.get(key)
-        if hit is not None and hit[0] == vers:
-            self.hits += len(hit[2])
-            self._stacked.move_to_end(key)          # LRU touch
-            return hit[1], list(hit[2])
-        gps, ids = [], []
-        for z in workload_ids:
-            gp = self.get(z, measure)
-            if gp is not None:
-                gps.append(gp)
-                ids.append(z)
-        # stack at the planner's observation bucket so repeated steps
-        # re-enter the query plans on already-bucketed shapes
-        stack = stack_gps(gps, round_to=OBS_ROUND_TO) if gps else None
-        # misses are rare (a repo version moved, or a new support set):
-        # use them to evict version-stale entries, so a long-running
-        # service's cache tracks the live support sets instead of
-        # accumulating dead padded stacks
-        stale = [k for k, (v, _, _) in self._stacked.items()
-                 if v != tuple(self._repo.version(z) for z in k[0])]
+        keys = [(tuple(ids), measure) for ids, measure in requests]
+        found: Dict[Tuple[Tuple[str, ...], str], Tuple[object, list]] = {}
+        misses: Dict[Tuple[Tuple[str, ...], str],
+                     Tuple[Tuple[int, ...], object, list]] = {}
+        for key in keys:
+            if key in found or key in misses:      # asked for twice
+                self.hits += len(found[key][1] if key in found
+                                 else misses[key][2])
+                continue
+            vers = tuple(self._repo.version(z) for z in key[0])
+            hit = self._stacked.get(key)
+            if hit is not None and hit[0] == vers:
+                self.hits += len(hit[2])
+                self._stacked.move_to_end(key)          # LRU touch
+                found[key] = (hit[1], hit[2])
+                continue
+            self.stack_misses += 1
+            gps, ids = [], []
+            for z in key[0]:
+                host = self._fitted(z, key[1])[2]
+                if host is not None:
+                    gps.append(host)
+                    ids.append(z)
+            # stack at the planner's observation bucket so repeated steps
+            # re-enter the query plans on already-bucketed shapes
+            misses[key] = (vers, stack_gps_host(gps, round_to=OBS_ROUND_TO)
+                           if gps else None, ids)
+        if misses:
+            built = [k for k, (_, st, _) in misses.items() if st is not None]
+            on_device = dict(zip(built, put_stacks(
+                [misses[k][1] for k in built])))
+            self._drop_stale()
+            for key, (vers, _, ids) in misses.items():
+                found[key] = (on_device.get(key), ids)
+                self._stacked[key] = (vers, on_device.get(key), ids)
+            # ... and the capacity bound evicts the least recently used
+            # live entries beyond it
+            while len(self._stacked) > self._max_entries:
+                self._stacked.popitem(last=False)
+                self.evictions += 1
+        return [(found[key][0], list(found[key][1])) for key in keys]
+
+    def _drop_stale(self) -> None:
+        """Evict version-stale stacks, so a long-running service's cache
+        tracks the live support sets instead of accumulating dead
+        padded stacks. An entry goes stale only when the repository
+        moves, so the sweep runs once per repository version."""
+        v = self._repo.global_version()
+        if v == self._swept_at:
+            return
+        self._swept_at = v
+        stale = [k for k, (vers, _, _) in self._stacked.items()
+                 if vers != tuple(self._repo.version(z) for z in k[0])]
         for k in stale:
             del self._stacked[k]
-        self._stacked[key] = (vers, stack, ids)
-        # ... and the capacity bound evicts the least recently used
-        # live entries beyond it
-        while len(self._stacked) > self._max_entries:
-            self._stacked.popitem(last=False)
-            self.evictions += 1
-        return stack, list(ids)
 
     def invalidate(self, workload_id: Optional[str] = None) -> None:
         """Drop cached fits (one workload, or everything)."""

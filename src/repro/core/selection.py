@@ -10,7 +10,9 @@ similarity scores; candidates sorted descending, best k returned.
 Two paths: the faithful pure-python loop (exactly Algorithm 1, used at
 search-time sizes) and a vectorised batch path over the whole repository
 using the ``pairwise_pearson`` kernel (the "proper distance operator" a
-real deployment needs, §IV-E).
+real deployment needs, §IV-E). The batch path serves many targets at
+once (``CandidateIndex.query_many``): a ``SearchService`` step scores
+every karasu tenant in one launch.
 """
 from __future__ import annotations
 
@@ -18,10 +20,13 @@ import math
 from collections import defaultdict
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.pairwise_pearson import pairwise_pearson
+from repro.kernels.pairwise_pearson.ops import _pearson_launch
+from repro.kernels.routing import resolve_impl
+from .plan import CAND_ROUND_TO, round_rows
 from .types import RunRecord
 
 
@@ -68,10 +73,12 @@ class CandidateIndex:
 
     Stacking every candidate run's metric vector / machine type / node
     count is O(repository) work; a multi-tenant ``SearchService`` runs
-    Algorithm 1 once per tenant per iteration against the *same*
+    Algorithm 1 for every tenant every iteration against the *same*
     repository snapshot, so the index is built once (and rebuilt only
-    when the repository version moves) and each query pays only the
-    pairwise-Pearson kernel plus a vectorised segment reduction."""
+    when the repository version moves) and each round of queries pays
+    one pairwise-Pearson launch plus vectorised segment reductions.
+    The candidate rows live on the device, padded with zero rows to a
+    multiple of ``plan.CAND_ROUND_TO``."""
 
     def __init__(self, candidates: Dict[str, Sequence[RunRecord]]):
         cand_ids: List[str] = []
@@ -88,26 +95,85 @@ class CandidateIndex:
             return
         self._zindex = {z: i for i, z in enumerate(self.workload_ids)}
         self._seg = np.array([self._zindex[z] for z in cand_ids])
-        self._metrics = jnp.asarray(
-            np.stack([r.metric_vector() for r in cand_runs]))
+        self.n_cand = len(cand_runs)
+        rows = np.stack([r.metric_vector() for r in cand_runs])
+        padded = np.zeros((-(-self.n_cand // CAND_ROUND_TO) * CAND_ROUND_TO,
+                           rows.shape[1]), np.float32)
+        padded[:self.n_cand] = rows
+        self._metrics = jnp.asarray(padded)
         self._types = np.array([r.machine_type for r in cand_runs])
         self._log_nodes = np.log2(
             np.array([max(r.node_count, 1) for r in cand_runs]))
+
+    @property
+    def metric_dim(self) -> int:
+        return int(self._metrics.shape[1])
+
+    def correlate(self, rows: np.ndarray, impl: str) -> np.ndarray:
+        """Pearson correlation of each metric row with every candidate,
+        ``(r, n_cand)`` on the host: one launch of ``_pearson_launch``
+        at the padded row count (``plan.round_rows``), read back once.
+        ``impl`` must be concrete (not ``"auto"``)."""
+        r = rows.shape[0]
+        a = np.zeros((round_rows(r), rows.shape[1]), np.float32)
+        a[:r] = rows
+        corr = jax.device_get(_pearson_launch(a, self._metrics, impl=impl))
+        return corr[:r, :self.n_cand]
 
     def query(self, target_runs: Sequence[RunRecord], k: int, *,
               impl: str = "xla", default_score: float = 0.5,
               exclude: Optional[Sequence[str]] = None
               ) -> List[Tuple[str, float]]:
-        """Top-k candidates; ``exclude`` drops workload ids before the
-        cut (e.g. a tenant's own published runs — which would otherwise
-        score ~1.0 against themselves and defeat the LOO safeguard)."""
-        if self.empty or not target_runs:
-            return []
-        a = np.stack([r.metric_vector() for r in target_runs])
-        corr = np.asarray(pairwise_pearson(jnp.asarray(a), self._metrics,
-                                           impl=impl))
-        sim = (corr + 1.0) / 2.0
+        """Top-k candidates of one target: ``query_many`` of one."""
+        return self.query_many([target_runs], k, impl=impl,
+                               default_score=default_score,
+                               exclude=[exclude])[0]
 
+    def query_many(self, targets: Sequence[Sequence[RunRecord]], k: int, *,
+                   impl: str = "xla", default_score: float = 0.5,
+                   exclude: Optional[Sequence[Optional[Sequence[str]]]]
+                   = None, counters: Optional[dict] = None
+                   ) -> List[List[Tuple[str, float]]]:
+        """Top-k candidates of each target run list, in input order (an
+        empty list gets ``[]``). Every target's metric rows go to ONE
+        Pearson launch per kernel impl — each target's ``impl``
+        resolves on its own cell count, as a query of it alone would —
+        and the weighting, segment reduction and cut run in numpy on the
+        target's rows. ``exclude[i]`` drops workload ids from target
+        ``i`` before the cut (e.g. a tenant's own published runs —
+        which would otherwise score ~1.0 against themselves and defeat
+        the LOO safeguard). ``counters["launches"]`` counts the Pearson
+        launches."""
+        out: List[List[Tuple[str, float]]] = [[] for _ in targets]
+        if self.empty:
+            return out
+        by_impl: Dict[str, List[int]] = {}
+        for i, runs in enumerate(targets):
+            if runs:
+                by_impl.setdefault(resolve_impl(
+                    impl, cells=len(runs) * self.n_cand), []).append(i)
+        for r_impl, idxs in by_impl.items():
+            corr = self.correlate(np.concatenate(
+                [np.stack([r.metric_vector() for r in targets[i]])
+                 for i in idxs]), r_impl)
+            off = 0
+            for i in idxs:
+                n = len(targets[i])
+                out[i] = self._top_k(
+                    targets[i], corr[off:off + n], k, default_score,
+                    exclude[i] if exclude is not None else None)
+                off += n
+            if counters is not None:
+                counters["launches"] = counters.get("launches", 0) + 1
+        return out
+
+    def _top_k(self, target_runs: Sequence[RunRecord], corr: np.ndarray,
+               k: int, default_score: float,
+               exclude: Optional[Sequence[str]]
+               ) -> List[Tuple[str, float]]:
+        """Algorithm 1's weighting and cut for one target, from its
+        ``(n_runs, n_cand)`` correlation rows."""
+        sim = (corr + 1.0) / 2.0
         t_types = np.array([r.machine_type for r in target_runs])
         t_nodes = np.log2(np.array([max(r.node_count, 1)
                                     for r in target_runs]))

@@ -47,3 +47,15 @@ def pairwise_pearson(a: jnp.ndarray, b: jnp.ndarray, *, impl: str = "xla"
     if impl == "pallas_interpret":
         return _pallas(a, b, interpret=True)
     raise ValueError(f"unknown pairwise_pearson impl {impl!r}")
+
+
+@functools.partial(jax.jit, static_argnames=("impl",))
+def _pearson_launch(a, b, impl: str = "xla"):
+    """The jitted (tracked) entry for pairwise Pearson — part of the
+    compile-once launch vocabulary (``launch.compile_stats``). A
+    ``SearchService`` step scores every karasu tenant's target runs
+    against the candidate index in one such launch; callers pass a
+    concrete ``impl`` and pad both row axes (``core.plan.round_rows``,
+    ``CAND_ROUND_TO``), so the shape set is closed by the cohort
+    bounds. All-zero pad rows correlate 0 with everything."""
+    return pairwise_pearson(a, b, impl=impl)

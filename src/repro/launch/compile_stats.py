@@ -3,7 +3,7 @@
 The compile-once steady state is a claim about a FINITE set of jitted
 launch functions: the fused fit, the per-kind plan launches (each with
 its buffer-donating twin), the fused posterior / fused EHVI kernels,
-and the support-model fit. This module registers exactly that set and
+the support-model fit, and the select phase's Pearson and key launches. This module registers exactly that set and
 counts their compiles via jit-cache sizes, so a service can assert
 "zero recompiles after precompile" instead of hoping for it. The
 support fit is unpadded and outside the precompiled vocabulary: it
@@ -68,7 +68,8 @@ _STATIC_NAMES = frozenset({
     "sample_donated", "loo", "loo_donated", "ehvi", "ehvi_donated",
     "fused_posterior", "fused_posterior_donated", "fused_ehvi",
     "fused_ehvi_donated", "fused_fit", "fused_fit_donated",
-    "ranking_loss", "ranking_loss_donated", "support_fit"})
+    "ranking_loss", "ranking_loss_donated", "support_fit", "pearson",
+    "derive_keys"})
 
 
 def register_launch(name: str, fn) -> None:
@@ -92,10 +93,11 @@ def register_launch(name: str, fn) -> None:
 def tracked_launches() -> Dict[str, object]:
     """name -> jitted launch fn, lazily imported (this module must stay
     importable before the heavy model modules are)."""
-    from repro.core import acquisition, gp
+    from repro.core import acquisition, bo, gp
     from repro.kernels.fused_ehvi import ops as fused_ehvi_ops
     from repro.kernels.fused_fit import ops as fused_fit_ops
     from repro.kernels.fused_posterior import ops as fused_ops
+    from repro.kernels.pairwise_pearson import ops as pearson_ops
     from repro.kernels.ranking_loss import ops as ranking_ops
 
     return {
@@ -121,6 +123,10 @@ def tracked_launches() -> Dict[str, object]:
         # the support-model fit ``SupportModelStore`` reaches through
         # ``fit_gp``: unpadded, so each new history length compiles
         "support_fit": gp._fit,
+        # the select phase's two step-wide launches: Algorithm 1 for
+        # every karasu tenant, and the step's RGPE keys
+        "pearson": pearson_ops._pearson_launch,
+        "derive_keys": bo._derive_keys_launch,
     }
 
 

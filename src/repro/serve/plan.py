@@ -20,11 +20,11 @@ from repro.core.plan import (GRID_ROUND_TO, M_ROUND_POW2, OBS_ROUND_TO,
                              Bucket, CohortLimits, EhviQuery, FitQuery,
                              LooSampleQuery, PlanExecutor,
                              PosteriorDrawQuery, PosteriorQuery,
-                             SampleQuery, StepPlan, StepPlanner)
+                             SampleQuery, StepPlan, StepPlanner, row_pads)
 
 __all__ = [
     "OBS_ROUND_TO", "GRID_ROUND_TO", "M_ROUND_POW2",
     "Bucket", "CohortLimits", "StepPlan", "StepPlanner", "PlanExecutor",
     "PosteriorQuery", "SampleQuery", "LooSampleQuery",
-    "PosteriorDrawQuery", "EhviQuery", "FitQuery",
+    "PosteriorDrawQuery", "EhviQuery", "FitQuery", "row_pads",
 ]

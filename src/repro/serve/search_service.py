@@ -72,12 +72,13 @@ from repro.core.bo import (KEY_PURPOSE_MOO_EHVI, KEY_PURPOSE_RGPE, BOConfig,
                            KarasuContext, ProfileFn, _acquisition,
                            _best_index_so_far, _feasible,
                            _model_posteriors_augmented, _should_stop_early,
-                           _target_runs, derive_key)
+                           _target_runs, derive_key, derive_keys)
 from repro.core.encoding import SearchSpace
 from repro.core.gp import (GP, BatchedGP, GPParams, _pad_stack_obs,
                            batched_posterior)
 from repro.core.repository import Repository
 from repro.core.rgpe import WeightJob, mix_weighted
+from repro.core.selection import CandidateIndex
 from repro.kernels.ranking_loss import ranking_loss_launch_fn
 from repro.core.types import (BOResult, Constraint, Objective, Observation,
                               RunRecord)
@@ -86,7 +87,7 @@ from repro.launch.spans import root, span
 from repro.serve.plan import (CohortLimits, EhviQuery, FitQuery,
                               LooSampleQuery, PlanExecutor,
                               PosteriorDrawQuery, PosteriorQuery,
-                              SampleQuery, StepPlan, StepPlanner)
+                              SampleQuery, StepPlan, StepPlanner, row_pads)
 from repro.serve.profile_executor import (ProfileJob, ProfileOutcome,
                                           SyncProfileExecutor)
 
@@ -149,6 +150,7 @@ class _Session:
         self.req = req
         self.cfg = req.bo_config
         self.key = jax.random.PRNGKey(req.seed)
+        self.key_data = np.asarray(self.key)   # host copy, for derive_keys
         self.rng = np.random.default_rng(req.seed)
         self.objectives = (list(req.objectives)
                            if req.objectives is not None else [])
@@ -317,10 +319,11 @@ class SearchService:
     # admit, absorb (poll/drain), profile_wait (the blocking collect when
     # every session waits), fit.collect (FitQuery building), fit.cache
     # (the warm-start refresh, a host transfer), regroup (target stacks
-    # and their posterior queries), select (candidate-index queries,
-    # support stacks, target extracts), score (RGPE weights, its sample
-    # round nested), moo.front (fronts and EHVI queries), acquire
-    # (per-tenant acquisition and the next runs' submits), finish
+    # and their posterior queries), select (one candidate-index launch,
+    # support stacks, host target slices, one key launch), score (RGPE
+    # weights, its sample round nested), moo.front (fronts and EHVI
+    # queries), acquire (per-tenant acquisition and the next runs'
+    # submits), finish
     STEP_PHASES = ("admit", "absorb", "profile_wait", "fit.collect",
                    "fit.cache", "regroup", "select", "score", "moo.front",
                    "acquire", "finish")
@@ -377,7 +380,9 @@ class SearchService:
                       "plan_compile_misses": 0, "precompiled_buckets": 0,
                       "precompile_compiles": 0,
                       "fit_warm_lanes": 0, "fit_cold_lanes": 0,
-                      "fit_fused_batches": 0}
+                      "fit_fused_batches": 0,
+                      "select_pearson_launches": 0, "select_tenants": 0,
+                      "support_stack_misses": 0}
         # ``step`` and ``precompile`` also add, as they occur, the spans'
         # self seconds (``span_s.<phase>``) and the programs each phase
         # built (``compiles.<phase>``): see ``repro.launch.spans``
@@ -484,14 +489,31 @@ class SearchService:
             # is the warmed vocabulary — a per-tenant Pallas override
             # opts out of the zero-recompile claim for this leg
             launch = ranking_loss_launch_fn(donate=self.plan_executor.donate)
-            row_pads = sorted({self.planner.round_models(k * s)
-                               for s in limits.n_samples
-                               for k in range(1, limits.max_lanes + 1)})
+            loss_rows = sorted({self.planner.round_models(k * s)
+                                for s in limits.n_samples
+                                for k in range(1, limits.max_lanes + 1)})
             for n_pad in self.planner._obs_pads(limits.max_obs):
-                for r_pad in row_pads:
+                for r_pad in loss_rows:
                     launch(jnp.zeros((r_pad, n_pad), jnp.float32),
                            jnp.zeros((r_pad, n_pad), jnp.float32),
                            jnp.zeros((r_pad,), jnp.int32), impl="xla")
+        # the select phase's two launches, at every row pad: Algorithm 1
+        # for the step's karasu tenants (each holds a target lane, so at
+        # most max_lanes of them, and at most max_obs runs each) against
+        # the repository's candidate index, and the step's RGPE keys
+        # (one per target lane). The Pearson impl follows the same
+        # cohort-default rule as the ranking loss; runs published later
+        # can move the candidate pad, which then compiles once
+        index = CandidateIndex(self.repo.all_runs())
+        if not index.empty:
+            tenants = min(self.slots, limits.max_lanes)
+            for r in row_pads(tenants * limits.max_obs):
+                index.correlate(np.zeros((r, index.metric_dim), np.float32),
+                                "xla")
+        key = np.asarray(jax.random.PRNGKey(0))
+        for r in row_pads(limits.max_lanes):
+            derive_keys(np.broadcast_to(key, (r,) + key.shape),
+                        KEY_PURPOSE_RGPE, [0] * r, [0] * r)
         self.precompiled_signatures = {
             self.planner.launch_signature(b) for b in buckets}
         compiles = watch.misses()
@@ -881,10 +903,7 @@ class SearchService:
 
         # (session, measure, bases, WeightJob) across ALL groups
         with span("select"):
-            rgpe_jobs: List[Tuple[_Session, str, Any, WeightJob]] = [
-                job for gk, group in groups.items() for s in group
-                if s.req.method == "karasu"
-                for job in self._rgpe_jobs(s, tgts[gk], owners[gk])]
+            rgpe_jobs = self._rgpe_jobs(groups, tgts, owners)
 
         with span("score"):
             weights = self._score_weights(rgpe_jobs)
@@ -945,33 +964,79 @@ class SearchService:
                            "y_mean": p["y_mean"], "y_std": p["y_std"],
                            "weights": np.asarray(w)}
 
-    def _rgpe_jobs(self, s: _Session, tgts, owners
+    def _rgpe_jobs(self, groups, tgts, owners
                    ) -> List[Tuple[_Session, str, Any, WeightJob]]:
-        """Queue one weighting job per measure whose support stack is
-        usable; key split matches the sequential path exactly."""
-        ctx = self.context_for(s)
-        # a tenant must never pick its own published runs as "support":
-        # they would score ~1.0 against themselves and sidestep the LOO
-        # sampling that keeps the target honest on its training points
-        exclude = (s.req.share_as,) if s.req.share_as else None
-        selected = ctx.candidate_index().query(
-            _target_runs(s.observations), s.cfg.n_support,
-            impl=s.cfg.kernel_impl, exclude=exclude)
-        s.meta["selected"].append([z for z, _ in selected])
-        if not selected:
+        """Queue one weighting job per (karasu session, measure) whose
+        support stack is usable, for every group of the step at once:
+        Algorithm 1 for all tenants in one Pearson launch per (context,
+        support count, kernel impl), support stacks from the shared
+        store, each target sliced from one host copy of its group's
+        target stack, and every key in one ``derive_keys`` launch. Jobs
+        come in (group, session, measure) order; keys match the
+        sequential path's ``derive_key`` schedule exactly."""
+        karasu = [(gk, s) for gk, group in groups.items() for s in group
+                  if s.req.method == "karasu"]
+        calls: Dict[Tuple[Any, int, str], List[_Session]] = {}
+        for _gk, s in karasu:
+            calls.setdefault((self.context_for(s), s.cfg.n_support,
+                              s.cfg.kernel_impl), []).append(s)
+        selected: Dict[int, List[Tuple[str, float]]] = {}
+        launches: Dict[str, int] = {}
+        for (ctx, k, impl), members in calls.items():
+            # a tenant must never pick its own published runs as
+            # "support": they would score ~1.0 against themselves and
+            # sidestep the LOO sampling that keeps the target honest on
+            # its training points
+            found = ctx.candidate_index().query_many(
+                [_target_runs(s.observations) for s in members], k,
+                impl=impl, counters=launches,
+                exclude=[(s.req.share_as,) if s.req.share_as else None
+                         for s in members])
+            selected.update((s.rid, f) for s, f in zip(members, found))
+        self.stats["select_pearson_launches"] += launches.get("launches", 0)
+        self.stats["select_tenants"] += len(karasu)
+
+        # support stacks: one batched request per store, so the stacks
+        # that miss go to the device in one transfer
+        wanted: Dict[Any, List[Tuple[int, str, List[str]]]] = {}
+        for _gk, s in karasu:
+            ids = [z for z, _ in selected[s.rid]]
+            s.meta["selected"].append(ids)
+            if ids:
+                wanted.setdefault(self.context_for(s).store, []).extend(
+                    (s.rid, m, ids) for m in s.measures)
+        stacks = {}
+        for store, reqs in wanted.items():
+            got = store.get_stacked_many([(ids, m) for _, m, ids in reqs])
+            stacks.update(((rid, m), bases)
+                          for (rid, m, _), (bases, _ids) in zip(reqs, got))
+        self.stats["support_stack_misses"] = sum(
+            c.store.stack_misses for c in self._contexts.values())
+        pending = [(gk, s, mi, m, stacks[s.rid, m]) for gk, s in karasu
+                   for mi, m in enumerate(s.measures)
+                   if stacks.get((s.rid, m)) is not None]
+        if not pending:
             return []
-        it = len(s.observations)
-        job_of = {m: ji for ji, (o, m) in enumerate(owners) if o is s}
-        jobs = []
-        for mi, m in enumerate(s.measures):
-            bases, _ids = ctx.store.get_stacked([z for z, _ in selected], m)
-            if bases is None:
-                continue
-            key = derive_key(s.key, KEY_PURPOSE_RGPE, it, mi)
-            jobs.append((s, m, bases,
-                         WeightJob(bases, tgts.extract(job_of[m]), key,
-                                   s.cfg.rgpe_samples)))
-        return jobs
+        keys = derive_keys(np.stack([p[1].key_data for p in pending]),
+                           KEY_PURPOSE_RGPE,
+                           [len(p[1].observations) for p in pending],
+                           [p[2] for p in pending])
+        # targets: each group's stack read to the host once and sliced in
+        # numpy; the slices the planned draws consume go back to the
+        # device in one batched transfer, so packing the draws costs no
+        # per-job transfer
+        host = {gk: tgts[gk].to_host() for gk in {p[0] for p in pending}}
+        lane = {gk: {(o.rid, m): ji for ji, (o, m) in enumerate(owners[gk])}
+                for gk in host}
+        targets = [host[gk].extract(lane[gk][s.rid, m])
+                   for gk, s, _mi, m, _b in pending]
+        put = jax.device_put([(t.x, t.y, t.chol, t.alpha) for t in targets])
+        return [(s, m, bases,
+                 WeightJob(bases, dataclasses.replace(
+                     t, x=x, y=y, chol=chol, alpha=alpha), key,
+                     s.cfg.rgpe_samples))
+                for (gk, s, _mi, m, bases), t, (x, y, chol, alpha), key
+                in zip(pending, targets, put, keys)]
 
     def _mix_rgpe(self, s: _Session, m: str, bases, w, post) -> None:
         """Replace one (session, measure) plain target posterior with the

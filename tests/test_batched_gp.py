@@ -4,6 +4,7 @@ query plan, impl routing, and weight invariants."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (batched_posterior, batched_posterior_multi,
                         batched_sample, batched_sample_multi, build_ensemble,
@@ -77,6 +78,64 @@ def test_stack_gps_is_exact_and_extract_roundtrips():
         mu2, _ = gp_posterior(g2, xq)
         np.testing.assert_allclose(np.asarray(mu2), np.asarray(mu),
                                    atol=1e-5)
+
+
+def test_host_target_slice_matches_extract_bitwise():
+    """``to_host`` reads a stack back once; ``extract`` on the host copy
+    gives numpy arrays equal bit for bit to the device ``extract``."""
+    xs, ys, _ = _models(sizes=(2, 5, 9, 14))
+    bgp = fit_gp_batched(xs, ys, round_to=8, m_round_pow2=True)
+    host = bgp.to_host()
+    for i in range(len(xs)):
+        h, d = host.extract(i), bgp.extract(i)
+        assert h.n == d.n == len(ys[i])
+        for f in ("x", "y_raw", "y", "y_mean", "y_std", "chol", "alpha"):
+            assert isinstance(getattr(h, f), (np.ndarray, np.generic)), f
+            np.testing.assert_array_equal(getattr(h, f),
+                                          np.asarray(getattr(d, f)))
+        for f in ("log_lengthscales", "log_signal"):
+            np.testing.assert_array_equal(getattr(h.params, f),
+                                          np.asarray(getattr(d.params, f)))
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_host_built_support_stack_matches_stack_gps_bitwise(batched):
+    """A support-stack miss stacks the host copies taken at fit time:
+    each stack equals ``stack_gps`` over the device GPs bit for bit,
+    whether asked for alone or among a batch (one transfer for all its
+    misses, a repeated key built once), and the store counts each
+    distinct miss once."""
+    from repro.core import Repository, SupportModelStore
+    from repro.core.plan import OBS_ROUND_TO
+    from repro.simdata import make_emulator
+    emu = make_emulator()
+    space = emu.space
+    repo = Repository()
+    rng = np.random.default_rng(3)
+    for u, n in enumerate((4, 7, 11)):
+        for ci in rng.choice(len(space), n, replace=False):
+            repo.add_run(emu.make_record(f"u{u}", emu.workload_ids()[u],
+                                         space.configs[ci], rng))
+    store = SupportModelStore(repo, space)
+    sets = ([["u0", "u1", "u2"]] if not batched
+            else [["u0", "u1", "u2"], ["u2", "u0"], ["u0", "u1", "u2"]])
+    if batched:
+        got = store.get_stacked_many([(ids, "cost") for ids in sets])
+        assert got[2][0] is got[0][0]
+    else:
+        got = [store.get_stacked(sets[0], "cost")]
+    assert store.stack_misses == (2 if batched else 1)
+    for ids, (stack, got_ids) in zip(sets, got):
+        assert got_ids == ids
+        ref = stack_gps([store.get(z, "cost") for z in ids],
+                        round_to=OBS_ROUND_TO)
+        for f in ("x", "y", "mask", "y_mean", "y_std", "log_lengthscales",
+                  "log_signal", "chol", "alpha", "counts"):
+            np.testing.assert_array_equal(np.asarray(getattr(stack, f)),
+                                          np.asarray(getattr(ref, f)))
+        assert stack.noise == ref.noise
+        assert store.get_stacked(ids, "cost")[0] is stack
+    assert store.stack_misses == (2 if batched else 1)
 
 
 def test_batched_sample_matches_per_model():

@@ -681,6 +681,154 @@ def test_prng_key_schedule_collision_free():
     assert len(seen) == 2 * 25 * 10
 
 
+@pytest.mark.parametrize("seed", [0, 42, 2**31 + 5, 3100001301])
+def test_derive_keys_match_derive_key_bitwise(seed):
+    """The step's RGPE keys come from ONE batched launch, read back as
+    host uint32 data: every row equals the per-call ``derive_key`` bit
+    for bit (ragged iterations and measure indices, across a padded
+    row count), and a raw host key splits exactly like the device
+    key it came from."""
+    import jax
+
+    from repro.core.bo import KEY_PURPOSE_RGPE, derive_key, derive_keys
+    bases = [jax.random.PRNGKey(seed + t) for t in range(3)]
+    rows = [(t, it, mi) for t in range(3) for it in (1, 7, 19)
+            for mi in range(t + 1)]
+    keys = derive_keys(np.stack([np.asarray(bases[t]) for t, _, _ in rows]),
+                       KEY_PURPOSE_RGPE, [it for _, it, _ in rows],
+                       [mi for _, _, mi in rows])
+    assert isinstance(keys, np.ndarray) and keys.dtype == np.uint32
+    for key, (t, it, mi) in zip(keys, rows):
+        ref = derive_key(bases[t], KEY_PURPOSE_RGPE, it, mi)
+        np.testing.assert_array_equal(key, np.asarray(ref))
+        np.testing.assert_array_equal(np.asarray(jax.random.split(key, 4)),
+                                      np.asarray(jax.random.split(ref, 4)))
+
+
+def _per_tenant_rgpe_jobs(svc, groups, tgts, owners):
+    """The select phase one tenant at a time, as it ran before the
+    step-wide batching: a candidate-index query, then per measure a
+    support stack, a device ``extract`` of the target and a
+    ``derive_key``."""
+    from repro.core.bo import KEY_PURPOSE_RGPE, _target_runs, derive_key
+    from repro.core.rgpe import WeightJob
+    jobs = []
+    for gk, group in groups.items():
+        for s in group:
+            if s.req.method != "karasu":
+                continue
+            ctx = svc.context_for(s)
+            exclude = (s.req.share_as,) if s.req.share_as else None
+            selected = ctx.candidate_index().query(
+                _target_runs(s.observations), s.cfg.n_support,
+                impl=s.cfg.kernel_impl, exclude=exclude)
+            s.meta["selected"].append([z for z, _ in selected])
+            if not selected:
+                continue
+            it = len(s.observations)
+            job_of = {m: ji for ji, (o, m) in enumerate(owners[gk])
+                      if o is s}
+            for mi, m in enumerate(s.measures):
+                bases, _ = ctx.store.get_stacked([z for z, _ in selected],
+                                                 m)
+                if bases is None:
+                    continue
+                key = derive_key(s.key, KEY_PURPOSE_RGPE, it, mi)
+                jobs.append((s, m, bases,
+                             WeightJob(bases, tgts[gk].extract(job_of[m]),
+                                       key, s.cfg.rgpe_samples)))
+    return jobs
+
+
+def test_step_wide_select_matches_per_tenant_loop():
+    """A small cohort with ragged observation counts (n_init 1, 2, 3),
+    run through ``precompile`` and 3 steps: the step-wide select (one
+    Pearson launch, host target slices, one key launch) gives the same
+    ``meta["selected"]``, the same job inputs bit for bit, the same RGPE
+    weights and the same decisions as the per-tenant loop — and no
+    tracked launch compiles after ``precompile`` but the support fits."""
+    import dataclasses
+
+    from repro.core.plan import CohortLimits
+
+    space = dataclasses.replace(SPACE, name="scout-mini",
+                                configs=SPACE.configs[:8])
+
+    def repo():
+        r = Repository()
+        rng = np.random.default_rng(11)
+        for u, wid in enumerate((WID, WIDS[2], WIDS[9])):
+            for ci in rng.choice(len(space), 6, replace=False):
+                r.add_run(EMU.make_record(f"anon-{u}", wid,
+                                          space.configs[ci], rng))
+        return r
+
+    def service():
+        svc = SearchService(repo(), slots=4)
+        for t in range(4):
+            cfg = BOConfig(n_init=1 + t % 3, max_iters=8, n_support=2,
+                           rgpe_samples=32)
+            svc.submit(SearchRequest(
+                space, lambda c: EMU.run(WID, c, rng=None),
+                Objective("cost"), [Constraint("runtime", RT)],
+                method="naive" if t == 3 else "karasu", bo_config=cfg,
+                seed=70 + t))
+        log = []
+        score = svc._score_weights
+
+        def scored(jobs):
+            ws = score(jobs)
+            log.append([(s.rid, m, job, np.asarray(ws[i]))
+                        for i, (s, m, _b, job) in enumerate(jobs)])
+            return ws
+        svc._score_weights = scored
+        return svc, log
+
+    svc, log = service()
+    svc.precompile(CohortLimits(d=space.all_encoded().shape[1], q_grid=8,
+                                max_obs=8, max_lanes=32, n_samples=(32,)))
+    watch = CompileWatcher()
+    for _ in range(3):
+        svc.step()
+    compiled = watch.delta()
+    compiled.pop("support_fit", 0)
+    assert compiled == {}
+    assert svc.stats["select_pearson_launches"] == 3
+    assert svc.stats["select_tenants"] == 9
+    assert svc.stats["support_stack_misses"] > 0
+
+    ref, ref_log = service()
+    ref._rgpe_jobs = lambda groups, tgts, owners: _per_tenant_rgpe_jobs(
+        ref, groups, tgts, owners)
+    for _ in range(3):
+        ref.step()
+    assert ref.stats["select_pearson_launches"] == 0
+
+    assert len(log) == len(ref_log) == 3
+    assert sum(len(step) for step in log) > 0
+    for step, ref_step in zip(log, ref_log):
+        assert [(rid, m) for rid, m, *_ in step] == \
+            [(rid, m) for rid, m, *_ in ref_step]
+        for (_, _, job, w), (_, _, rjob, rw) in zip(step, ref_step):
+            np.testing.assert_array_equal(job.key, np.asarray(rjob.key))
+            for f in ("x", "y_raw", "y", "y_mean", "y_std", "chol",
+                      "alpha"):
+                np.testing.assert_array_equal(
+                    getattr(job.target, f),
+                    np.asarray(getattr(rjob.target, f)))
+            for f in ("log_lengthscales", "log_signal"):
+                np.testing.assert_array_equal(
+                    getattr(job.target.params, f),
+                    np.asarray(getattr(rjob.target.params, f)))
+            np.testing.assert_array_equal(w, rw)
+    assert set(svc.active) == set(ref.active) == {0, 1, 2, 3}
+    for rid, s in svc.active.items():
+        r = ref.active[rid]
+        assert s.meta["selected"] == r.meta["selected"]
+        assert [o.config for o in s.observations] == \
+            [o.config for o in r.observations]
+
+
 def test_prng_consumers_bitwise_deterministic():
     """Bit-for-bit determinism across BOTH derived-key consumers (RGPE
     support draws and MOO EHVI draws) on the fake executor: a karasu

@@ -1,7 +1,9 @@
 """Algorithm 1 selection + repository semantics."""
 import numpy as np
+import pytest
 
 from repro.core import Repository, RunRecord, select_similar, select_similar_batched
+from repro.core.selection import CandidateIndex
 from repro.simdata import make_emulator
 
 
@@ -35,6 +37,49 @@ def test_selection_prefers_same_algorithm():
     d1 = dict(ranked); d2 = dict(batched)
     for z in d1:
         np.testing.assert_allclose(d1[z], d2[z], atol=1e-6)
+
+
+@pytest.mark.parametrize("case", ["plain", "exclude", "empty_target"])
+def test_query_many_matches_per_target_query(case):
+    """``query_many`` scores ragged targets (1 to 8 runs) in one Pearson
+    launch: each target's top-k is the one its own ``query`` gives, its
+    scores are within 1e-6 of the faithful Algorithm-1 loop, a target's
+    ``exclude`` drops only its own ids, and an empty target gets
+    ``[]``."""
+    emu = make_emulator()
+    space = emu.space
+    wids = emu.workload_ids()
+    candidates = {f"c{j}": _records(emu, f"c{j}", wids[j], 9, 10 + j,
+                                    space) for j in range(6)}
+    targets = [_records(emu, "t", wids[(3 * t) % len(wids)], n, 40 + t,
+                        space) for t, n in enumerate((1, 3, 5, 8))]
+    excludes = [None] * len(targets)
+    if case == "exclude":
+        excludes = [None, ("c0", "c3"), None, ("c5",)]
+    if case == "empty_target":
+        targets.insert(2, [])
+        excludes.insert(2, None)
+    index = CandidateIndex(candidates)
+    counters = {}
+    many = index.query_many(targets, 4, exclude=excludes,
+                            counters=counters)
+    assert counters == {"launches": 1}
+    assert len(many) == len(targets)
+    for runs, excl, got in zip(targets, excludes, many):
+        if not runs:
+            assert got == []
+            continue
+        one = index.query(runs, 4, exclude=excl)
+        assert [z for z, _ in got] == [z for z, _ in one]
+        np.testing.assert_allclose([v for _, v in got],
+                                   [v for _, v in one], atol=1e-6)
+        full = dict(select_similar(runs, candidates, k=len(candidates)))
+        for z, v in got:
+            np.testing.assert_allclose(v, full[z], atol=1e-6)
+        assert not set(excl or ()) & {z for z, _ in got}
+        kept = [z for z, _ in sorted(full.items(), key=lambda t: -t[1])
+                if z not in set(excl or ())]
+        assert len(got) == min(4, len(kept))
 
 
 def test_repository_roundtrip(tmp_path):
