@@ -433,18 +433,19 @@ class StepPlanner:
     def _pads_ehvi(self, key, queries, idxs, prep) -> Dict[str, int]:
         n_obj = key[0]
         k_max = 1
-        for i, query in zip(idxs, queries):
-            observed = np.asarray(query.observed, np.float64)
-            if observed.size and (observed.ndim != 2
-                                  or observed.shape[1] != n_obj):
-                raise ValueError(
-                    f"EhviQuery observed has shape {observed.shape} but "
-                    f"carries {n_obj} objective sample arrays")
-            los, his = nondominated_boxes(
-                pareto_front(observed.reshape(-1, n_obj)),
-                np.asarray(query.ref, np.float64))
-            prep[i] = (los, his)
-            k_max = max(k_max, los.shape[0])
+        with span("ehvi.boxes"):
+            for i, query in zip(idxs, queries):
+                observed = np.asarray(query.observed, np.float64)
+                if observed.size and (observed.ndim != 2
+                                      or observed.shape[1] != n_obj):
+                    raise ValueError(
+                        f"EhviQuery observed has shape {observed.shape} "
+                        f"but carries {n_obj} objective sample arrays")
+                los, his = nondominated_boxes(
+                    pareto_front(observed.reshape(-1, n_obj)),
+                    np.asarray(query.ref, np.float64))
+                prep[i] = (los, his)
+                k_max = max(k_max, los.shape[0])
         return {"k_pad": self.round_boxes(k_max),
                 "q_pad": self.round_grid(key[2]),
                 "l_pad": self.round_models(len(queries)),
@@ -609,19 +610,28 @@ class StepPlanner:
 
 
 def _count(counters: Optional[dict], kind: str, queries: int,
-           lanes: int) -> None:
+           lanes: int, **extra: int) -> None:
     if counters is None:
         return
     c = counters.setdefault(kind, {})
-    c["launches"] = c.get("launches", 0) + 1
-    c["queries"] = c.get("queries", 0) + queries
-    c["lanes"] = c.get("lanes", 0) + lanes
+    for k, v in dict(launches=1, queries=queries, lanes=lanes,
+                     **extra).items():
+        c[k] = c.get(k, 0) + v
+
+
+def _box_counts(bucket: Bucket, plan: StepPlan) -> Dict[str, int]:
+    """An EHVI launch's boxes over its live lanes: each lane's own box
+    count, and the box axis it was padded to."""
+    return {"boxes_live": sum(int(plan.prep[i][0].shape[0])
+                              for i in bucket.indices),
+            "boxes_padded": bucket.pads["k_pad"] * len(bucket.indices)}
 
 
 def flatten_counters(nested: dict, counters: Optional[dict],
                      kinds: Sequence[str]) -> None:
     """Merge ``execute``'s per-kind counters into the historical flat
-    ``launches``/``queries``/``lanes`` dict the single-kind wrappers
+    ``launches``/``queries``/``lanes`` dict (EHVI adds ``boxes_live`` /
+    ``boxes_padded``) the single-kind wrappers
     (``batched_posterior_multi`` & co.) expose."""
     if counters is None:
         return
@@ -884,7 +894,9 @@ class PlanExecutor:
             _count(counters, bucket.kind, len(queries),
                    bucket.pads.get("m_pad",
                                    bucket.pads.get("l_pad",
-                                                   bucket.pads["lanes"])))
+                                                   bucket.pads["lanes"])),
+                   **(_box_counts(bucket, plan) if bucket.kind == "ehvi"
+                      else {}))
         with span("scatter"):
             for query, result in zip(plan.queries, results):
                 if callable(query.owner):

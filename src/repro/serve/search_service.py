@@ -382,7 +382,8 @@ class SearchService:
                       "fit_warm_lanes": 0, "fit_cold_lanes": 0,
                       "fit_fused_batches": 0,
                       "select_pearson_launches": 0, "select_tenants": 0,
-                      "support_stack_misses": 0}
+                      "support_stack_misses": 0, "ehvi_boxes_live": 0,
+                      "ehvi_boxes_padded": 0}
         # ``step`` and ``precompile`` also add, as they occur, the spans'
         # self seconds (``span_s.<phase>``) and the programs each phase
         # built (``compiles.<phase>``): see ``repro.launch.spans``
@@ -751,14 +752,18 @@ class SearchService:
 
     def _count_plan(self, counters: Dict[str, Dict[str, int]]) -> None:
         """Roll one planned round's per-kind counters into the service
-        stats: the per-kind pairs (``_STAT_KEYS``) plus the aggregate
-        ``plan_batches``/``plan_queries``."""
+        stats: the per-kind pairs (``_STAT_KEYS``), the aggregate
+        ``plan_batches``/``plan_queries``, and the EHVI launches' live
+        and padded boxes (``ehvi_boxes_live``/``ehvi_boxes_padded``)."""
         for kind, c in counters.items():
             bk, qk = self._STAT_KEYS[kind]
             self.stats[bk] += c.get("launches", 0)
             self.stats[qk] += c.get("queries", 0)
             self.stats["plan_batches"] += c.get("launches", 0)
             self.stats["plan_queries"] += c.get("queries", 0)
+            if kind == "ehvi":
+                self.stats["ehvi_boxes_live"] += c.get("boxes_live", 0)
+                self.stats["ehvi_boxes_padded"] += c.get("boxes_padded", 0)
 
     @staticmethod
     def _regroup_fit(entries: List[Tuple[BatchedGP, int]],

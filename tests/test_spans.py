@@ -115,7 +115,7 @@ EMU = make_emulator()
 WID = EMU.workload_ids()[6]
 
 
-def _cohort():
+def _cohort(moo=True):
     space = scout_search_space()
     space = dataclasses.replace(space, name="scout-10",
                                 configs=space.configs[:10])
@@ -131,10 +131,11 @@ def _cohort():
     runner = lambda c: EMU.run(WID, c, rng=None)      # noqa: E731
     svc.submit(SearchRequest(space, runner, Objective("cost"), cons,
                              method="karasu", bo_config=cfg, seed=1))
-    svc.submit(SearchRequest(space, runner, None, cons, method="karasu",
-                             bo_config=cfg, seed=2,
-                             objectives=[Objective("cost"),
-                                         Objective("energy")], n_mc=8))
+    if moo:
+        svc.submit(SearchRequest(space, runner, None, cons,
+                                 method="karasu", bo_config=cfg, seed=2,
+                                 objectives=[Objective("cost"),
+                                             Objective("energy")], n_mc=8))
     return svc
 
 
@@ -229,3 +230,48 @@ def test_compiles_are_charged_to_step_phases(traced_steps):
     assert first.get("compiles.launch", 0) > 0
     phases = set(SearchService.STEP_PHASES + PLAN_SPANS) | {"step"}
     assert {k[len("compiles."):] for k in first} <= phases
+
+
+# -- the EHVI box decomposition: its span and its counters --------------------
+
+def test_box_span_only_in_multi_objective_steps(traced_steps):
+    _, deltas, events = traced_steps
+    # the cohort's two-objective tenant decides on every traced step
+    assert all(d.get("span_s.ehvi.boxes", 0) > 0 for d in deltas)
+    plans = [(s, e) for n, s, e, _ in events if n == "karasu.plan"]
+    boxes = [(s, e) for n, s, e, _ in events if n == "karasu.ehvi.boxes"]
+    assert boxes and all(any(ps <= s and e <= pe for ps, pe in plans)
+                         for s, e in boxes)
+
+    svc = _cohort(moo=False)
+    for _ in range(3):
+        svc.step()
+    assert svc.stats["iterations"] > 0
+    assert "span_s.ehvi.boxes" not in svc.stats
+    assert svc.stats["ehvi_boxes_live"] == svc.stats["ehvi_boxes_padded"] == 0
+
+
+def test_box_counters_sum_live_and_padded_boxes():
+    """A two-lane EHVI bucket, fronts of 2 and 5 mutually non-dominated
+    points: staircases of 3 and 6 boxes, both lanes padded to 8."""
+    from repro.core.plan import EhviQuery, PlanExecutor, StepPlanner
+    rng = np.random.default_rng(4)
+    fronts = [np.array([[1.0, 4.0], [3.0, 2.0]]),
+              np.array([[1.0, 9.0], [2.0, 7.0], [3.0, 5.0], [4.0, 3.0],
+                        [5.0, 1.0]])]
+    queries = [EhviQuery(tuple(rng.normal(2.0, 1.0, (4, 6))
+                               for _ in range(2)), f,
+                         f.max(axis=0) * 1.1 + 1e-9) for f in fronts]
+    plan = StepPlanner().plan(queries)
+    assert [b.pads["k_pad"] for b in plan.buckets] == [8]
+    nested = {}
+    PlanExecutor().execute(plan, counters=nested)
+    assert nested["ehvi"]["boxes_live"] == 3 + 6
+    assert nested["ehvi"]["boxes_padded"] == 2 * 8
+
+    svc = SearchService(Repository(), slots=2)
+    svc._count_plan(nested)
+    svc._count_plan(nested)
+    assert svc.stats["ehvi_boxes_live"] == 18
+    assert svc.stats["ehvi_boxes_padded"] == 32
+    assert svc.stats["ehvi_boxes_live"] <= svc.stats["ehvi_boxes_padded"]
