@@ -102,10 +102,10 @@ CAND_ROUND_TO = 128
 class PosteriorQuery:
     """Posterior mean/variance of one ``BatchedGP`` stack on a grid.
     ``grid``: (q, d) shared across the stack's models or (m, q, d)
-    per-model. Result: ``(mu, var)``, each (m, q) — or ``(mu, var, ei)``
-    when ``best`` (the standardised-scale incumbent for the closed-form
-    minimisation-EI head) is set, letting the fused bucket kernel finish
-    the acquisition in the same launch."""
+    per-model. Result: ``(mu, var)``, each an (m, q) host numpy array,
+    or ``(mu, var, ei)`` when ``best`` (the standardised-scale incumbent
+    for the closed-form minimisation-EI head) is set, letting the fused
+    bucket kernel finish the acquisition in the same launch."""
     stack: BatchedGP
     grid: Any
     owner: Any = None
@@ -138,7 +138,8 @@ class PosteriorDrawQuery:
     """Raw-scale affine draws from precomputed posterior rows — the MOO
     EHVI sampling leg, where the grid posterior already ran and only
     ``mu + eps * sqrt(var)`` (rescaled) remains. ``mu``/``var``: (q,)
-    standardised rows at the remaining candidates. Result: (n_mc, q)."""
+    standardised rows at the remaining candidates. Result: (n_mc, q)
+    host numpy."""
     mu: Any
     var: Any
     y_mean: Any
@@ -627,11 +628,26 @@ def _box_counts(bucket: Bucket, plan: StepPlan) -> Dict[str, int]:
             "boxes_padded": bucket.pads["k_pad"] * len(bucket.indices)}
 
 
+def _host_counts(kind: str, queries) -> Dict[str, int]:
+    """The rows a launch hands its owners as host arrays, and the
+    device-to-host reads made for them: a posterior launch reads ``mu``
+    and ``var`` (and the EI row when a query asks for it), a draw or
+    EHVI launch its one output. Other kinds hand back device arrays."""
+    if kind == "posterior":
+        return {"host_unpack_rows": sum(q.stack.m for q in queries),
+                "host_unpack_reads": 2 + any(q.best is not None
+                                             for q in queries)}
+    if kind in ("draw", "ehvi"):
+        return {"host_unpack_rows": len(queries), "host_unpack_reads": 1}
+    return {}
+
+
 def flatten_counters(nested: dict, counters: Optional[dict],
                      kinds: Sequence[str]) -> None:
     """Merge ``execute``'s per-kind counters into the historical flat
     ``launches``/``queries``/``lanes`` dict (EHVI adds ``boxes_live`` /
-    ``boxes_padded``) the single-kind wrappers
+    ``boxes_padded``; posterior, draw and EHVI add ``host_unpack_rows`` /
+    ``host_unpack_reads``) the single-kind wrappers
     (``batched_posterior_multi`` & co.) expose."""
     if counters is None:
         return
@@ -780,19 +796,23 @@ def _draw_launch(keys, mu, var, y_std, y_mean, n_mc: int):
     return sm * y_std[:, None, None] + y_mean[:, None, None]
 
 
+def _put_rows(fields) -> List[Any]:
+    """Each field's per-lane host rows (posterior rows or scalars)
+    stacked in numpy float32, and all of them put on the device in one
+    call: the draw packing costs no per-lane transfer."""
+    return jax.device_put([np.stack([np.asarray(a, np.float32) for a in f])
+                           for f in fields])
+
+
 def _materialise_ehvi_draws(query, s: int, q: int):
     """Raw-scale draws of a posterior-form ``EhviQuery`` on the vmapped
     (non-fused) path: one ``_draw_launch`` over the query's objectives,
     consuming the same per-objective keys the fused kernel would — so
     the two executors' EHVI rows agree to float roundoff."""
     keys = jnp.stack([jnp.asarray(k) for k in query.keys])
-    parts = [jnp.stack([jnp.asarray(a, jnp.float32) for a in t])
-             for t in (query.mu, query.var)]
-    scal = [jnp.asarray(np.asarray(t, np.float32)) for t in
-            (query.y_std, query.y_mean)]
-    draws = _draw_launch(keys, parts[0], parts[1], scal[0], scal[1],
-                         n_mc=s)
-    return [draws[d] for d in range(draws.shape[0])]
+    draws = _draw_launch(keys, *_put_rows(
+        [query.mu, query.var, query.y_std, query.y_mean]), n_mc=s)
+    return list(np.asarray(draws))
 
 
 class PlanExecutor:
@@ -802,6 +822,16 @@ class PlanExecutor:
     that overlay earlier owners' state — e.g. RGPE mixes over target
     posteriors — see a deterministic sequence). ``counters`` (optional
     dict) collects ``{kind: {launches, queries, lanes}}``.
+
+    Results come back where their consumers run. The posterior, draw
+    and EHVI legs feed host code only (RGPE mixes, acquisitions, Pareto
+    fronts, the EHVI round's numpy packing), so each such launch's
+    outputs are read to the host once (``mu``/``var`` and any EI row,
+    the draws, the EHVI rows) and every query gets numpy views of its
+    rows; ``counters`` adds ``host_unpack_rows`` (rows handed over so)
+    and ``host_unpack_reads`` (the reads made for them). The sample,
+    LOO and fit legs feed further launches (the ranking loss, the
+    posterior round), so their results stay device arrays.
 
     ``fused_posterior=True`` dispatches posterior buckets to the fused
     ``kernels.fused_posterior`` launch (masked Cholesky-solve ->
@@ -896,7 +926,8 @@ class PlanExecutor:
                                    bucket.pads.get("l_pad",
                                                    bucket.pads["lanes"])),
                    **(_box_counts(bucket, plan) if bucket.kind == "ehvi"
-                      else {}))
+                      else {}),
+                   **_host_counts(bucket.kind, queries))
         with span("scatter"):
             for query, result in zip(plan.queries, results):
                 if callable(query.owner):
@@ -953,21 +984,21 @@ class PlanExecutor:
     def _exec_posterior(self, bucket, queries, plan, impl):
         q, d = bucket.key
         n_pad, m_pad = bucket.pads["n_pad"], bucket.pads["m_pad"]
+        with_ei = any(query.best is not None for query in queries)
         with span("pack", kind="posterior"):
             parts = self._fresh_parts(
                 queries, self._stack_parts(queries, n_pad, q, d))
             r_impl = self.bucket_impl(bucket, impl)
+            # per-lane incumbents; lanes without an EI head get 0.0 (the
+            # EI row is computed for every lane — shape stability — and
+            # simply not returned for those queries)
+            best = np.concatenate([
+                np.full((query.stack.m,),
+                        0.0 if query.best is None else float(query.best),
+                        np.float32) for query in queries])
             if self.fused_posterior:
                 from repro.kernels.fused_posterior import fused_launch_fn
-                # per-lane incumbents; lanes without an EI head get 0.0
-                # (the EI row is computed either way — shape stability —
-                # and simply not returned for those queries)
-                best = jnp.concatenate([
-                    jnp.full((query.stack.m,),
-                             0.0 if query.best is None
-                             else float(query.best),
-                             jnp.float32) for query in queries])
-                parts = self._pad_lanes(parts + [best], m_pad)
+                parts = self._pad_lanes(parts + [jnp.asarray(best)], m_pad)
                 launch = self._launch("fused_posterior",
                                       fused_launch_fn(donate=False),
                                       fused_launch_fn(donate=True))
@@ -978,18 +1009,24 @@ class PlanExecutor:
         with span("launch", kind="posterior"):
             res = launch(*parts, impl=r_impl)
         mu, var = res[:2]
-        ei = res[2] if self.fused_posterior else None
         with span("unpack", kind="posterior"):
+            outs = (mu, var)
+            if with_ei:
+                # the vmapped chain has no EI head: the same
+                # expected_improvement over the launch's rows, one call
+                ei = (res[2] if self.fused_posterior else
+                      expected_improvement(mu, var, jnp.asarray(np.pad(
+                          best, (0, m_pad - best.size)))[:, None]))
+                outs += (ei,)
+            # the rows' consumers (RGPE mixes, acquisitions, fronts) are
+            # host code: each output is read to the host once and every
+            # query gets numpy views of its rows
+            host = jax.device_get(outs)
             out, off = [], 0
             for query in queries:
                 rows = slice(off, off + query.stack.m)
-                if query.best is None:
-                    out.append((mu[rows], var[rows]))
-                elif ei is not None:
-                    out.append((mu[rows], var[rows], ei[rows]))
-                else:
-                    out.append((mu[rows], var[rows], expected_improvement(
-                        mu[rows], var[rows], float(query.best))))
+                out.append(tuple(h[rows] for h in (
+                    host if query.best is not None else host[:2])))
                 off += query.stack.m
         return out
 
@@ -1059,13 +1096,15 @@ class PlanExecutor:
     def _exec_draw(self, bucket, queries, plan, impl):
         n_mc, _q = bucket.key
         with span("pack", kind="draw"):
-            parts = [jnp.stack([jnp.asarray(getattr(query, f))
-                                for query in queries])
-                     for f in ("key", "mu", "var", "y_std", "y_mean")]
+            keys = jnp.stack([jnp.asarray(query.key) for query in queries])
+            parts = _put_rows([[getattr(query, f) for query in queries]
+                               for f in ("mu", "var", "y_std", "y_mean")])
         with span("launch", kind="draw"):
-            draws = _draw_launch(*parts, n_mc=n_mc)
+            draws = _draw_launch(keys, *parts, n_mc=n_mc)
         with span("unpack", kind="draw"):
-            return [draws[j] for j in range(len(queries))]
+            # the EHVI round's host packing consumes the draws
+            host = np.asarray(draws)
+            return [host[j] for j in range(len(queries))]
 
     def _exec_ehvi(self, bucket, queries, plan, impl):
         if self.fused_ehvi:
@@ -1095,7 +1134,8 @@ class PlanExecutor:
         with span("launch", kind="ehvi"):
             out = launch(*parts)
         with span("unpack", kind="ehvi"):
-            return [np.asarray(out[j])[:q] for j in range(len(queries))]
+            host = np.asarray(out)
+            return [host[j, :q] for j in range(len(queries))]
 
     @staticmethod
     def _ehvi_fronts(bucket, queries, plan):
@@ -1177,7 +1217,8 @@ class PlanExecutor:
         with span("launch", kind="ehvi"):
             out = launch(*parts, impl=r_impl)
         with span("unpack", kind="ehvi"):
-            return [np.asarray(out[j])[:q] for j in range(len(queries))]
+            host = np.asarray(out)
+            return [host[j, :q] for j in range(len(queries))]
 
     def _exec_fit(self, bucket, queries, plan, impl):
         """One ``kernels.fused_fit`` launch for the bucket: pack the raw

@@ -333,11 +333,19 @@ def mix_weighted(mu_b: jnp.ndarray, var_b: jnp.ndarray,
     """RGPE mixture from stacked base posterior rows ``(m, q)`` plus the
     target row ``(q,)``; ``w`` is ``(m+1,)`` with the target LAST. The
     one mixing rule every path (run_search, run_search_moo, the service)
-    applies after its grid posteriors come back from the query plan."""
+    applies after its grid posteriors come back from the query plan.
+    It runs in the array module of its rows: host (numpy) rows, as the
+    plan executor hands them back, mix in numpy float32 on the host;
+    device rows mix as jax ops on the device."""
+    xp = np if all(isinstance(a, np.ndarray)
+                   for a in (mu_b, var_b, mu_t, var_t)) else jnp
+    if xp is np:
+        mu_b, var_b, mu_t, var_t, w = (np.asarray(a, np.float32) for a in
+                                       (mu_b, var_b, mu_t, var_t, w))
     wb, wt = w[:-1, None], w[-1]
-    mu = jnp.sum(wb * mu_b, axis=0) + wt * mu_t
-    var = jnp.sum((wb ** 2) * var_b, axis=0) + (wt ** 2) * var_t
-    return mu, jnp.maximum(var, 1e-10)
+    mu = xp.sum(wb * mu_b, axis=0) + wt * mu_t
+    var = xp.sum((wb ** 2) * var_b, axis=0) + (wt ** 2) * var_t
+    return mu, xp.maximum(var, 1e-10)
 
 
 def ensemble_posterior_batched(ens: BatchedEnsemble, xq: jnp.ndarray, *,

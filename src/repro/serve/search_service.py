@@ -110,11 +110,13 @@ KEY_SCHEDULE = (
 def _absorb_target_posts(posts, owners, tgts, mu, var) -> None:
     """Record one target stack's grid-posterior rows into each owning
     (session, measure) slot — shared by the fused plan and the loop
-    fallback so the posterior dict shape cannot diverge between them."""
+    fallback so the posterior dict shape cannot diverge between them.
+    The stack's scalers come from one host copy, not a read per lane."""
+    y_mean, y_std = jax.device_get((tgts.y_mean, tgts.y_std))
     for ji, (s, m) in enumerate(owners):
         posts.setdefault(s.rid, {})[m] = {
             "mu": mu[ji], "var": var[ji],
-            "y_mean": tgts.y_mean[ji], "y_std": tgts.y_std[ji]}
+            "y_mean": y_mean[ji], "y_std": y_std[ji]}
 
 
 @dataclasses.dataclass
@@ -383,7 +385,8 @@ class SearchService:
                       "fit_fused_batches": 0,
                       "select_pearson_launches": 0, "select_tenants": 0,
                       "support_stack_misses": 0, "ehvi_boxes_live": 0,
-                      "ehvi_boxes_padded": 0}
+                      "ehvi_boxes_padded": 0, "host_unpack_rows": 0,
+                      "host_unpack_reads": 0}
         # ``step`` and ``precompile`` also add, as they occur, the spans'
         # self seconds (``span_s.<phase>``) and the programs each phase
         # built (``compiles.<phase>``): see ``repro.launch.spans``
@@ -753,14 +756,18 @@ class SearchService:
     def _count_plan(self, counters: Dict[str, Dict[str, int]]) -> None:
         """Roll one planned round's per-kind counters into the service
         stats: the per-kind pairs (``_STAT_KEYS``), the aggregate
-        ``plan_batches``/``plan_queries``, and the EHVI launches' live
-        and padded boxes (``ehvi_boxes_live``/``ehvi_boxes_padded``)."""
+        ``plan_batches``/``plan_queries``, the EHVI launches' live and
+        padded boxes (``ehvi_boxes_live``/``ehvi_boxes_padded``), and
+        the rows handed back as host arrays with the reads made for them
+        (``host_unpack_rows``/``host_unpack_reads``)."""
         for kind, c in counters.items():
             bk, qk = self._STAT_KEYS[kind]
             self.stats[bk] += c.get("launches", 0)
             self.stats[qk] += c.get("queries", 0)
             self.stats["plan_batches"] += c.get("launches", 0)
             self.stats["plan_queries"] += c.get("queries", 0)
+            for k in ("host_unpack_rows", "host_unpack_reads"):
+                self.stats[k] += c.get(k, 0)
             if kind == "ehvi":
                 self.stats["ehvi_boxes_live"] += c.get("boxes_live", 0)
                 self.stats["ehvi_boxes_padded"] += c.get("boxes_padded", 0)
@@ -961,13 +968,15 @@ class SearchService:
     @staticmethod
     def _mix_into(posts, s: _Session, m: str, w, res) -> None:
         """Owner callback of an RGPE support-stack query: overlay the
-        weighted mixture on the already-scattered target posterior."""
+        weighted mixture on the already-scattered target posterior. The
+        executor hands back host rows, so the mix runs in numpy."""
         mu, var = res
         p = posts[s.rid][m]
+        w = np.asarray(w)
         mu_m, var_m = mix_weighted(mu, var, p["mu"], p["var"], w)
         posts[s.rid][m] = {"mu": mu_m, "var": var_m,
                            "y_mean": p["y_mean"], "y_std": p["y_std"],
-                           "weights": np.asarray(w)}
+                           "weights": w}
 
     def _rgpe_jobs(self, groups, tgts, owners
                    ) -> List[Tuple[_Session, str, Any, WeightJob]]:
@@ -1118,7 +1127,7 @@ class SearchService:
                         p["mu"][idx], p["var"][idx], p["y_mean"],
                         p["y_std"], k, s.req.n_mc,
                         owner=lambda d, rid=s.rid, oi=oi:
-                            samples[rid].__setitem__(oi, np.asarray(d))))
+                            samples[rid].__setitem__(oi, d)))
         dc: Dict[str, Dict[str, int]] = {}
         self.plan_executor.execute(self.planner.plan(draw_queries),
                                    counters=dc)

@@ -271,6 +271,153 @@ def test_plan_executor_fused_posterior_matches_default():
                                        atol=TOL)
 
 
+# -- where each leg's results live --------------------------------------------
+
+
+@pytest.fixture
+def launched(monkeypatch):
+    """Every bucket launch's device outputs as ``(kind, out)``, in launch
+    order: the executor's jitted launches and the unjitted draw."""
+    from repro.core import plan as plan_mod
+    outs = []
+
+    def recording(kind, fn):
+        def run(*args, **kw):
+            out = fn(*args, **kw)
+            outs.append((kind, out))
+            return out
+        return run
+
+    launch = PlanExecutor._launch
+    monkeypatch.setattr(PlanExecutor, "_launch",
+                        lambda self, kind, *a: recording(
+                            kind, launch(self, kind, *a)))
+    monkeypatch.setattr(plan_mod, "_draw_launch",
+                        recording("draw", plan_mod._draw_launch))
+    return outs
+
+
+def _host_leg_queries(rng):
+    """Two posterior buckets (one with an EI head), a draw bucket, and
+    an EHVI bucket mixing the sample and posterior forms."""
+    st_a, st_b = _stack(rng, (5, 9)), _stack(rng, (4,))
+    obs = np.array([[1.0, 3.0], [2.0, 2.0], [3.0, 1.0]])
+    ref = np.array([4.0, 4.0])
+    sa = rng.normal(2.0, 1.0, (16, 9)).astype(np.float32)
+    rows = [rng.normal(size=9).astype(np.float32) for _ in range(2)]
+    vrows = [rng.uniform(0.1, 1.0, 9).astype(np.float32) for _ in range(2)]
+    keys = [jax.random.PRNGKey(i) for i in range(4)]
+    return [
+        PosteriorQuery(st_a, rng.random((13, 3))),
+        PosteriorQuery(st_b, rng.random((13, 3)), best=0.4),
+        PosteriorQuery(st_a, rng.random((7, 3))),
+        PosteriorDrawQuery(rows[0], vrows[0], np.float32(2.0),
+                           np.float32(3.0), keys[0], 16),
+        PosteriorDrawQuery(rows[1], vrows[1], np.float32(-1.0),
+                           np.float32(0.5), keys[1], 16),
+        EhviQuery((sa, sa + 1.0), obs, ref),
+        EhviQuery(None, obs, ref, mu=tuple(rows), var=tuple(vrows),
+                  y_mean=(2.0, -1.0), y_std=(3.0, 0.5),
+                  keys=tuple(keys[2:]), n_mc=16),
+    ]
+
+
+@pytest.mark.parametrize("fused", [False, True],
+                         ids=["vmapped", "fused"])
+def test_host_legs_hand_back_numpy_rows(launched, fused):
+    """The posterior, draw and EHVI legs (vmapped, and the fused
+    posterior and EHVI kernels) hand each query numpy rows, bitwise
+    equal to slicing the launch's own device outputs; the EI row of a
+    query with ``best`` equals ``expected_improvement`` of its rows."""
+    from repro.core.acquisition import expected_improvement
+    rng = np.random.default_rng(31)
+    queries = _host_leg_queries(rng)
+    plan = StepPlanner().plan(queries)
+    ex = PlanExecutor(fused_posterior=fused, fused_ehvi=fused,
+                      impl="xla", donate=False)
+    res = ex.execute(plan)
+    by_kind = {}
+    for kind, out in launched:
+        by_kind.setdefault(kind.replace("fused_", ""), []).append(out)
+    for b in plan.buckets:
+        # buckets launch in plan order; a vmapped EHVI bucket's
+        # posterior-form lane draws after the draw bucket's launch
+        out = by_kind[b.kind].pop(0)
+        off = 0
+        for j, i in enumerate(b.indices):
+            r = res[i]
+            if b.kind == "posterior":
+                m = queries[i].stack.m
+                rows = slice(off, off + m)
+                off += m
+                want = [out[0][rows], out[1][rows]]
+                if queries[i].best is not None:
+                    want.append(out[2][rows] if fused else
+                                expected_improvement(out[0][rows],
+                                                     out[1][rows],
+                                                     queries[i].best))
+                assert len(r) == len(want)
+            else:
+                r, want = [r], [out[j] if b.kind == "draw"
+                                else out[j][:9]]
+            for got, w in zip(r, want):
+                assert isinstance(got, np.ndarray)
+                np.testing.assert_array_equal(got, np.asarray(w))
+
+
+def test_device_legs_hand_back_device_arrays():
+    """The sample, LOO and fit legs feed further launches (the ranking
+    loss, the posterior round), so their results stay on the device."""
+    rng = np.random.default_rng(32)
+    st = _stack(rng, (5, 9))
+    gp = fit_gp(rng.random((6, 3)), rng.random(6))
+    res = PlanExecutor().execute(StepPlanner().plan([
+        SampleQuery(st, rng.random((6, 3)),
+                    jax.random.split(jax.random.PRNGKey(0), 2), 8),
+        LooSampleQuery(gp, jax.random.PRNGKey(1), 8),
+        _fit_q(rng, 6, 16)]))
+    assert isinstance(res[0], jax.Array) and res[0].shape == (2, 8, 6)
+    assert isinstance(res[1], jax.Array) and res[1].shape == (8, 6)
+    stack, lane = res[2]
+    assert lane == 0
+    for f in ("x", "y", "chol", "alpha", "log_lengthscales"):
+        assert isinstance(getattr(stack, f), jax.Array), f
+
+
+def test_host_unpack_counters_of_a_mixed_plan():
+    """``host_unpack_rows`` counts the posterior lanes plus one row per
+    draw and EHVI query; ``host_unpack_reads`` is 2 per posterior launch
+    (3 where a query asks for the EI row) and 1 per draw or EHVI launch;
+    the sample, LOO and fit legs add neither."""
+    from repro.core.plan import flatten_counters
+    rng = np.random.default_rng(33)
+    st = _stack(rng, (5, 9))
+    gp = fit_gp(rng.random((6, 3)), rng.random(6))
+    queries = _host_leg_queries(rng) + [
+        SampleQuery(st, rng.random((6, 3)),
+                    jax.random.split(jax.random.PRNGKey(0), 2), 8),
+        LooSampleQuery(gp, jax.random.PRNGKey(1), 8),
+        _fit_q(rng, 6, 16)]
+    c = {}
+    PlanExecutor().execute(StepPlanner().plan(queries), counters=c)
+    # posterior buckets: (13, 3) carries 2 + 1 lanes and an EI head,
+    # (7, 3) carries 2 lanes
+    assert c["posterior"]["launches"] == 2
+    assert c["posterior"]["host_unpack_rows"] == 5
+    assert c["posterior"]["host_unpack_reads"] == 3 + 2
+    assert c["draw"]["host_unpack_rows"] == 2
+    assert c["draw"]["host_unpack_reads"] == 1
+    assert c["ehvi"]["host_unpack_rows"] == 2
+    assert c["ehvi"]["host_unpack_reads"] == 1
+    for kind in ("sample", "loo", "fit"):
+        assert "host_unpack_rows" not in c[kind]
+        assert "host_unpack_reads" not in c[kind]
+    flat = {}
+    flatten_counters(c, flat, ("posterior", "draw", "ehvi", "sample"))
+    assert flat["host_unpack_rows"] == 9
+    assert flat["host_unpack_reads"] == 7
+
+
 # -- plan stats on a live mixed cohort ---------------------------------------
 
 

@@ -3,6 +3,8 @@ async profiling (ProfileExecutor backends + WAITING_PROFILE overlap),
 fused posterior/acquisition query plan, multi-objective sessions."""
 import threading
 
+import jax
+
 import numpy as np
 import pytest
 
@@ -827,6 +829,137 @@ def test_step_wide_select_matches_per_tenant_loop():
         assert s.meta["selected"] == r.meta["selected"]
         assert [o.config for o in s.observations] == \
             [o.config for o in r.observations]
+
+
+def test_host_rows_match_device_loop_after_precompile():
+    """A small cohort of single-objective and two-objective karasu
+    tenants, over several steps after ``precompile``: the host-row
+    posteriors (numpy, RGPE-mixed in numpy) equal the
+    ``fuse_posteriors=False`` loop (device rows, mixed as jax ops)
+    within float32 roundoff, the launched configurations are the same,
+    no tracked launch compiles in the window, and the host-unpack
+    counters follow the executed plans: the posterior lanes plus one
+    row per draw and EHVI query, 2 reads per posterior launch and 1 per
+    draw or EHVI launch."""
+    import dataclasses
+
+    from repro.core.plan import CohortLimits, PlanExecutor
+
+    space = dataclasses.replace(SPACE, name="scout-mini",
+                                configs=SPACE.configs[:12])
+
+    class Recording(PlanExecutor):
+        def execute(self, plan, **kw):
+            for b in plan.buckets:
+                qs = [plan.queries[i] for i in b.indices]
+                if b.kind == "posterior":
+                    self.rows += sum(q.stack.m for q in qs)
+                    self.reads += 2
+                elif b.kind in ("draw", "ehvi"):
+                    self.rows += len(qs)
+                    self.reads += 1
+            return super().execute(plan, **kw)
+
+    def service(fuse):
+        repo = Repository()
+        rng = np.random.default_rng(11)
+        # as many support workloads as n_support: every support model is
+        # fitted in the first step, so none compiles in the window
+        for u, wid in enumerate((WID, WIDS[2])):
+            for ci in rng.choice(len(space), 8, replace=False):
+                repo.add_run(EMU.make_record(f"anon-{u}", wid,
+                                             space.configs[ci], rng))
+        ex = Recording()
+        ex.rows = ex.reads = 0
+        svc = SearchService(repo, slots=4, fuse_posteriors=fuse,
+                            plan_executor=ex)
+        for t in range(4):
+            kw = (dict(objective=Objective("cost"),
+                       constraints=[Constraint("runtime", RT)]) if t < 2
+                  else dict(objectives=[Objective("cost"),
+                                        Objective("energy")], n_mc=8))
+            svc.submit(SearchRequest(
+                space, lambda c: EMU.run(WID, c, rng=None),
+                method="karasu", seed=40 + t,
+                bo_config=BOConfig(n_init=2, max_iters=8, n_support=2,
+                                   rgpe_samples=32), **kw))
+        log = []
+        phase = svc._posterior_phase
+
+        def recorded(sessions):
+            posts = phase(sessions)
+            log.append(posts)
+            return posts
+        svc._posterior_phase = recorded
+        return svc, log
+
+    host, host_log = service(True)
+    loop, loop_log = service(False)
+    host.precompile(CohortLimits(
+        d=space.all_encoded().shape[1], q_grid=12, max_obs=8,
+        max_lanes=32, n_samples=(32,), n_mc=(8,), n_objectives=(2,),
+        max_ehvi_boxes=16))
+    host.step()
+    loop.step()
+    ex = host.plan_executor
+    rows0, reads0 = ex.rows, ex.reads
+    stats0 = dict(host.stats)
+    watch = CompileWatcher()
+    for _ in range(3):
+        host.step()
+        loop.step()
+    assert watch.delta() == {}
+    assert host.stats["host_unpack_rows"] - stats0["host_unpack_rows"] \
+        == ex.rows - rows0 > 0
+    assert host.stats["host_unpack_reads"] - stats0["host_unpack_reads"] \
+        == ex.reads - reads0 > 0
+
+    assert len(host_log) == len(loop_log) == 4
+    mixed = 0
+    for posts_h, posts_l in zip(host_log, loop_log):
+        assert posts_h.keys() == posts_l.keys()
+        for rid in posts_h:
+            for m, p in posts_h[rid].items():
+                q = posts_l[rid][m]
+                mixed += "weights" in p
+                assert ("weights" in p) == ("weights" in q)
+                for f in ("mu", "var"):
+                    assert isinstance(p[f], np.ndarray)
+                    assert p[f].dtype == np.float32
+                    np.testing.assert_allclose(p[f], np.asarray(q[f]),
+                                               rtol=1e-6)
+                for f in ("y_mean", "y_std"):
+                    assert float(p[f]) == float(q[f])
+    assert mixed > 0
+    assert host.active.keys() == loop.active.keys() == {0, 1, 2, 3}
+    for rid, s in host.active.items():
+        assert [o.config for o in s.observations] == \
+            [o.config for o in loop.active[rid].observations]
+
+
+def test_mix_weighted_same_rows_on_numpy_and_jax():
+    """``mix_weighted`` evaluates its one formula in the array module
+    of its rows: numpy rows mix on the host in float32, device rows as
+    jax ops, and both give the same rows."""
+    import jax.numpy as jnp
+
+    from repro.core.rgpe import mix_weighted
+    rng = np.random.default_rng(5)
+    mu_b = rng.normal(size=(3, 69)).astype(np.float32)
+    var_b = rng.uniform(1e-12, 2.0, (3, 69)).astype(np.float32)
+    mu_t = rng.normal(size=69).astype(np.float32)
+    var_t = rng.uniform(1e-12, 2.0, 69).astype(np.float32)
+    var_t[:4] = 0.0                      # the variance floor engages
+    w = rng.dirichlet(np.ones(4)).astype(np.float32)
+    w[-1] = 1e-4
+    host = mix_weighted(mu_b, var_b, mu_t, var_t, jnp.asarray(w))
+    dev = mix_weighted(*(jnp.asarray(a)
+                         for a in (mu_b, var_b, mu_t, var_t, w)))
+    for h, d in zip(host, dev):
+        assert isinstance(h, np.ndarray) and h.dtype == np.float32
+        assert isinstance(d, jax.Array)
+        np.testing.assert_allclose(h, np.asarray(d), rtol=1e-6)
+    assert host[1][:4].min() >= np.float32(1e-10)
 
 
 def test_prng_consumers_bitwise_deterministic():
